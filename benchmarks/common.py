@@ -8,6 +8,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.launch.cache import use_compile_cache
+
+# every benchmark imports this module before it compiles anything
+use_compile_cache()
+
 
 def make_param_state(nbytes: int, seed: int = 0) -> dict:
     """Synthetic 'model + Adam moments' pytree of ~nbytes total."""

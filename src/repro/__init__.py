@@ -3,7 +3,7 @@ hybrid-parallel training — production-grade JAX reproduction.
 
 Subpackages: api (unified checkpointing facade), core (the paper), models,
 configs, optim, data, dist, ckpt, kernels (Pallas TPU), launch, plus
-tests/ benchmarks/ examples/ at the repo root. See README.md / DESIGN.md /
-EXPERIMENTS.md and docs/API.md.
+tests/ benchmarks/ examples/ at the repo root and `chip_smoke.py`, the
+one-chip end-to-end check.  See docs/API.md and ROADMAP.md.
 """
 __version__ = "1.0.0"

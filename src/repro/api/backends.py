@@ -134,10 +134,9 @@ class ReftCheckpointer(Checkpointer):
             yield_every_buckets=opt.get("yield_every_buckets", 4),
             boundary_timeout_s=opt.get("boundary_timeout_s", 0.005),
             # device-side encode + multi-flight (docs/API.md
-            # "Device-side encode"): fused Pallas gather+XOR+CRC before
+            # "Device-side encode"): device gather + Pallas XOR/CRC before
             # d2h, overlapped flights, saving-path CPU pinning
             device_encode=opt.get("device_encode", "auto"),
-            crc_impl=opt.get("crc_impl", "pallas"),
             max_flights=opt.get("max_flights", 1),
             pin_cpus=opt.get("pin_cpus", "auto"),
             # async-persistence knobs (docs/API.md "Async persistence"):
@@ -368,6 +367,7 @@ class ReftCheckpointer(Checkpointer):
             members[e.node] = {
                 "state": st.value,
                 "degraded": e.degraded,
+                "error": e.first_error,
                 "smp_alive": smp_alive,
                 "last_clean_step": e.last_clean_step,
             }

@@ -67,7 +67,7 @@ class ModelConfig:
     fsdp: bool = False               # additionally shard params over data(+pod)
     remat: bool = True               # activation checkpointing on the scan body
     remat_policy: str = "full"       # full | dots (save matmul outputs)
-    # beyond-paper perf knobs (see EXPERIMENTS.md §Perf)
+    # beyond-paper perf knobs
     chunked_ce: int = 0              # >0: sequence-chunked cross-entropy
     window_kv_cache: bool = False    # SWA layers cache only the window
     banded_attention: bool = False   # skip out-of-window KV blocks (SWA)
@@ -265,7 +265,7 @@ def _load_all():
 
 
 def shape_supported(cfg: ModelConfig, shape: InputShape) -> Tuple[bool, str]:
-    """Whether (arch, shape) is exercised; reason recorded in DESIGN.md."""
+    """Whether (arch, shape) is exercised; the reason is the returned string."""
     if shape.kind == "decode" and not cfg.supports_decode:
         return False, "encoder-only architecture has no decode step"
     if shape.name == "long_500k" and not cfg.sub_quadratic:

@@ -1,11 +1,13 @@
 """CRC32 helpers for the device-side snapshot encode path.
 
-The device encode kernel (`repro.kernels.stage`) computes one CRC32 per
-bucket on the accelerator (slice-by-4 table lookups over uint32 lanes).
-Buckets cover the own region exactly once but arrive in schedule order
-(optimizer-moments first), so the host recombines the per-bucket digests
-into the contiguous own-region CRC with `crc32_combine` — an O(log len)
-GF(2) matrix fold per bucket instead of a full zlib pass over the bytes.
+The device encode kernel (`repro.kernels.stage`) computes 1024 chunk
+CRC32s per bucket on the accelerator (bitwise, one chunk per vector
+lane); `crc32_fold_chunks` folds them into the bucket's digest on the
+host with one vectorised GF(2) pass.  Buckets cover the own region
+exactly once but arrive in schedule order (optimizer-moments first), so
+the host recombines the per-bucket digests into the contiguous own-region
+CRC with `crc32_combine` — an O(log len) GF(2) matrix fold per bucket
+instead of a full zlib pass over the bytes.
 The combined value is byte-for-byte what `zlib.crc32` returns over the
 same region, so recovery's `verify_crc` needs no changes.
 """
@@ -16,30 +18,7 @@ from typing import Iterable, Tuple
 
 import numpy as np
 
-_POLY = 0xEDB88320          # reflected CRC-32 (IEEE 802.3), zlib-compatible
-
-
-def _make_slice4_tables() -> np.ndarray:
-    """(4, 256) uint32 lookup tables.  tables[0] is the classic byte-at-a-
-    time table; tables[k][i] advances the remainder k extra zero bytes, so
-    one uint32 word is consumed with four lookups (slice-by-4)."""
-    t0 = np.zeros(256, np.uint64)
-    for i in range(256):
-        c = i
-        for _ in range(8):
-            c = (c >> 1) ^ _POLY if c & 1 else c >> 1
-        t0[i] = c
-    tabs = [t0]
-    for _ in range(3):
-        prev = tabs[-1]
-        t = np.zeros(256, np.uint64)
-        for i in range(256):
-            t[i] = (prev[i] >> 8) ^ t0[prev[i] & 0xFF]
-        tabs.append(t)
-    return np.stack(tabs).astype(np.uint32)
-
-
-CRC_TABLES = _make_slice4_tables()
+POLY = 0xEDB88320           # reflected CRC-32 (IEEE 802.3), zlib-compatible
 
 
 # ------------------------------------------------------------- combining
@@ -66,7 +45,7 @@ def _zero_operator(len2: int) -> tuple:
     combine after the first is one 32-step matrix-vector product instead
     of ~45 pure-Python matrix squarings."""
     odd = [0] * 32
-    odd[0] = _POLY                       # one zero bit
+    odd[0] = POLY                       # one zero bit
     for i in range(1, 32):
         odd[i] = 1 << (i - 1)
     even = _gf2_square(odd)              # two zero bits
@@ -112,4 +91,48 @@ def crc32_concat(parts: Iterable[Tuple[int, int]]) -> int:
     crc = 0
     for part_crc, nbytes in parts:
         crc = crc32_combine(crc, part_crc, nbytes)
+    return crc
+
+
+_BITS = np.arange(32, dtype=np.uint32)
+
+
+def _apply(cols: np.ndarray, vecs: np.ndarray) -> np.ndarray:
+    """GF(2) operator (32 columns) applied to each uint32 in `vecs`."""
+    bits = ((vecs[..., None] >> _BITS) & 1).astype(bool)
+    return np.bitwise_xor.reduce(np.where(bits, cols, np.uint32(0)), axis=-1)
+
+
+@functools.lru_cache(maxsize=32)
+def _power_columns(chunk_bytes: int, count: int) -> np.ndarray:
+    """(count, 32) uint32: row k holds the columns of the operator that
+    advances a CRC register past k * `chunk_bytes` zero bytes."""
+    op = np.array(_zero_operator(chunk_bytes), np.uint32)
+    out = np.empty((count, 32), np.uint32)
+    cur = np.uint32(1) << _BITS                     # identity
+    for k in range(count):
+        out[k] = cur
+        cur = _apply(op, cur)
+    return out
+
+
+def crc32_fold_chunks(digests: np.ndarray, chunk_bytes: int,
+                      nbytes: int) -> int:
+    """CRC32 of `nbytes` bytes from the zlib CRC32s of its consecutive
+    `chunk_bytes`-long chunks (the last live chunk may be shorter; chunks
+    past `nbytes` are ignored).  Equal to `crc32_concat` over the same
+    parts, in one vectorised pass: crc = XOR_j M^(N-1-j) crc_j over the N
+    full chunks, M the zero-advance operator of one chunk."""
+    digests = np.asarray(digests, np.uint32).reshape(-1)
+    nfull, tail = divmod(int(nbytes), int(chunk_bytes))
+    assert nfull + (tail > 0) <= digests.size, \
+        f"{digests.size} chunk digests cannot cover {nbytes} bytes"
+    crc = 0
+    if nfull:
+        cols = _power_columns(int(chunk_bytes), digests.size)[nfull - 1::-1]
+        bits = ((digests[:nfull, None] >> _BITS) & 1).astype(bool)
+        crc = int(np.bitwise_xor.reduce(
+            np.where(bits, cols, np.uint32(0)), axis=None))
+    if tail:
+        crc = crc32_combine(crc, int(digests[nfull]), tail)
     return crc

@@ -126,34 +126,43 @@ def step_boundary() -> None:
 
 
 # --------------------------------------------------------- mode resolution
-def resolve_device_encode(cfg) -> bool:
-    """`ReftConfig.device_encode`: "on" forces the device encode path
-    (interpret-mode kernels on CPU — what CI exercises), "off" forces the
-    host path, "auto" enables it exactly when a real accelerator backs
-    the default JAX backend."""
-    mode = str(getattr(cfg, "device_encode", "auto")).lower()
+def on_accelerator(leaves: Sequence[Any]) -> bool:
+    """True iff any leaf is a JAX array resident on a non-CPU device.
+    Numpy leaves (a cluster node, a host-only test) are host state: no
+    backend is consulted, let alone initialised, to decide that."""
+    for leaf in leaves:
+        devices = getattr(leaf, "devices", None)
+        if callable(devices) and any(d.platform != "cpu"
+                                     for d in devices()):
+            return True
+    return False
+
+
+def _resolve(mode, leaves: Sequence[Any]) -> bool:
+    mode = str(mode).lower()
     if mode in ("on", "true", "1"):
         return True
     if mode in ("off", "false", "0"):
         return False
-    import jax
-    return jax.default_backend() != "cpu"
+    return on_accelerator(leaves)
 
 
-def resolve_ranged_fetch(cfg) -> bool:
+def resolve_device_encode(cfg, leaves: Sequence[Any]) -> bool:
+    """`ReftConfig.device_encode`: "on" forces the device encode path
+    (interpret-mode kernels for CPU-resident leaves — what CI exercises),
+    "off" forces the host path, "auto" takes the device path exactly when
+    the leaves being saved live on an accelerator."""
+    return _resolve(getattr(cfg, "device_encode", "auto"), leaves)
+
+
+def resolve_ranged_fetch(cfg, leaves: Sequence[Any]) -> bool:
     """`ReftConfig.ranged_fetch`: slice each leaf down to the byte extent
     a sparse delta flight actually reads *on the device* before the d2h
-    copy.  "on"/"off" force it; "auto" enables it exactly when a real
-    accelerator backs the default JAX backend — on the CPU backend
-    `np.asarray` of a leaf is already zero-copy, so device-side slicing
-    is pure dispatch overhead there."""
-    mode = str(getattr(cfg, "ranged_fetch", "auto")).lower()
-    if mode in ("on", "true", "1"):
-        return True
-    if mode in ("off", "false", "0"):
-        return False
-    import jax
-    return jax.default_backend() != "cpu"
+    copy.  "on"/"off" force it; "auto" enables it exactly when the leaves
+    live on an accelerator — for CPU-resident leaves `np.asarray` is
+    already zero-copy, so device-side slicing is pure dispatch overhead
+    there."""
+    return _resolve(getattr(cfg, "ranged_fetch", "auto"), leaves)
 
 
 def resolve_affinity(pin) -> Optional[Tuple[int, ...]]:
@@ -454,65 +463,43 @@ class LeafReader:
 class DeviceEncoder:
     """Device-side bucket encode for one flight: gathers a `BucketTask`'s
     scattered leaf byte-ranges into a contiguous uint32 lane buffer *on
-    the accelerator* (uint8 bitcast views of the pinned leaves, sliced and
-    concatenated device-side), then runs the fused Pallas kernel
-    (`repro.kernels.stage.encode_bucket`) — XOR parity fold for kind-2
-    buckets, CRC32 for own-data buckets — and pre-warms the d2h copy.
-    The host receives ready-to-publish bytes + digest; no per-leaf host
-    gather, no host XOR, no host zlib."""
+    the accelerator* (`repro.kernels.stage.place_bytes`: a windowed
+    slice of each leaf in its own layout, packed into words with shifts),
+    then runs the encode kernels (`repro.kernels.stage.encode_bucket`) —
+    XOR parity fold for kind-2 buckets, CRC32 for own-data buckets — and
+    pre-warms the d2h copy.  The host receives ready-to-publish bytes +
+    digest; no per-leaf host gather, no host XOR, no host zlib."""
 
-    def __init__(self, spec: FlatSpec, leaves: List[Any], *,
-                 interpret: Optional[bool] = None,
-                 crc_impl: str = "pallas"):
-        import jax  # noqa: F401  (device path requires jax at runtime)
+    def __init__(self, spec: FlatSpec, leaves: List[Any]):
         import jax.numpy as jnp
         from repro.kernels.stage import (LANE_BYTES, bucket_crc,
-                                         encode_bucket, pack_lanes)
+                                         encode_bucket, place_bytes)
         self._jnp = jnp
         self._lane_bytes = LANE_BYTES
         self._encode = encode_bucket
         self._bucket_crc = bucket_crc
-        self._pack = pack_lanes
+        self._place = place_bytes
         self.spec = spec
         self.leaves = leaves
         self.offsets = [l.offset for l in spec.leaves]
-        self.interpret = interpret
-        self.crc_impl = crc_impl
-        self._u8cache: Dict[int, Any] = {}
-
-    def _u8(self, i: int):
-        got = self._u8cache.get(i)
-        if got is None:
-            import jax
-            jnp = self._jnp
-            arr = jnp.asarray(self.leaves[i])
-            if arr.dtype == jnp.bool_:
-                arr = arr.astype(jnp.uint8)
-            if arr.dtype != jnp.uint8:
-                arr = jax.lax.bitcast_convert_type(arr, jnp.uint8)
-            got = self._u8cache[i] = arr.reshape(-1)
-        return got
 
     def gather_lanes(self, lo: int, hi: int):
         """Bytes [lo, hi) of the flat stream as (n_lanes,) uint32 on
         device, zero-padded past `total_bytes` and up to whole lanes."""
-        jnp = self._jnp
         nb = hi - lo
-        parts = []
+        n_lanes = -(-nb // self._lane_bytes) * (self._lane_bytes // 4)
+        out = self._jnp.zeros(n_lanes, self._jnp.uint32)
         i = bisect.bisect_right(self.offsets, lo) - 1
         pos = lo
         while pos < hi and i < len(self.spec.leaves):
             ls = self.spec.leaves[i]
             a, b = max(pos, ls.offset), min(hi, ls.offset + ls.nbytes)
             if b > a:
-                parts.append(self._u8(i)[a - ls.offset:b - ls.offset])
+                out = self._place(out, self.leaves[i], a - ls.offset,
+                                  a - lo, b - a)
             pos = b
             i += 1
-        pad = (hi - pos) + ((-nb) % self._lane_bytes)
-        if pad:
-            parts.append(jnp.zeros(pad, jnp.uint8))
-        u8 = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-        return self._pack(u8)
+        return out
 
     def encode(self, task: BucketTask, *, want_crc: Optional[bool] = None,
                prewarm_payload: bool = True):
@@ -532,20 +519,14 @@ class DeviceEncoder:
         else:
             rows = self.gather_lanes(task.lo, task.hi)[None]
             want_crc = True
-        lanes, crc = self._encode(rows, nbytes=nb, want_crc=want_crc,
-                                  interpret=self.interpret,
-                                  crc_impl=self.crc_impl)
-        warm = (lanes, crc) if prewarm_payload else (crc,)
-        for a in warm:
-            try:
-                a.copy_to_host_async()
-            except AttributeError:
-                pass
+        lanes, crc = self._encode(rows, nbytes=nb, want_crc=want_crc)
+        for a in (lanes, crc) if prewarm_payload else (crc,):
+            a.copy_to_host_async()
         return lanes, crc, nb
 
     def bucket_crc(self, crc, nbytes: int) -> int:
-        """Digest array (single-cell or per-tile, already on host) -> the
-        bucket's zlib-compatible CRC32 (crc32_combine fold for tiles)."""
+        """Chunk digests (already on host) -> the bucket's zlib-compatible
+        CRC32."""
         return self._bucket_crc(crc, nbytes)
 
 
@@ -973,11 +954,15 @@ class SnapshotPipeline:
 
     def __init__(self, smp, spec: FlatSpec, cfg,
                  own_plan: Sequence[Tuple[int, int, int]],
-                 stripe_plan: Sequence[Tuple[int, int]]):
+                 stripe_plan: Sequence[Tuple[int, int]],
+                 template_leaves: Sequence[Any] = ()):
         self.smp, self.spec, self.cfg = smp, spec, cfg
-        self.device_encode = resolve_device_encode(cfg)
-        self.ranged_fetch = resolve_ranged_fetch(cfg)
-        self.crc_impl = getattr(cfg, "crc_impl", "pallas")
+        # the bucket schedule depends on the mode, so it is fixed here from
+        # the state template's placement; `start` refuses leaves placed
+        # elsewhere instead of saving them down the wrong path
+        self.accelerator = on_accelerator(template_leaves)
+        self.device_encode = resolve_device_encode(cfg, template_leaves)
+        self.ranged_fetch = resolve_ranged_fetch(cfg, template_leaves)
         self.max_flights = max(1, int(getattr(cfg, "max_flights", 1)))
         self.delta_enabled = bool(getattr(cfg, "delta", False))
         # delta mode always fuses parity (host path included): a delta
@@ -1026,12 +1011,17 @@ class SnapshotPipeline:
             raise RuntimeError(
                 f"max_flights={self.max_flights} snapshots already in "
                 f"flight")
+        if on_accelerator(leaves) != self.accelerator:
+            raise ValueError(
+                f"snapshot leaves are {'' if self.accelerator else 'not '}"
+                f"expected on an accelerator (the engine's state template "
+                f"was{'' if self.accelerator else ' not'}): build the "
+                f"engine from a template placed like the state it saves")
         prev = self._last if (self._last is not None
                               and self._last.in_flight()) else None
         if prev is None:
             self._replenish()
-        encoder = DeviceEncoder(self.spec, leaves,
-                                crc_impl=self.crc_impl) \
+        encoder = DeviceEncoder(self.spec, leaves) \
             if self.device_encode else None
         flight = PipelineFlight(
             self.smp, self.spec, self.cfg, self.schedule, self.budget,

@@ -1,5 +1,5 @@
 """Sharding-group construction over the production mesh (paper §4.1,
-generalized per DESIGN.md §2).
+generalized to the production mesh).
 
 On the TPU mesh, parameters are sharded over the "model" axis and
 replicated (or FSDP-sharded) over "data" (+"pod").  A *sharding group* is

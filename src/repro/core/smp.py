@@ -52,45 +52,35 @@ def _seg(run: str, node: int, what: str) -> str:
     return f"reft-{run}-n{node}-{what}"
 
 
-import inspect as _inspect
+# Every process that maps a segment registers it with the resource
+# tracker, which then unlinks it behind our back (and races other
+# processes' messages into noisy KeyErrors).  REFT segments must outlive
+# any single process — that is the whole point of the SMP design — and
+# their lifetime is managed explicitly via unlink_node(), so exempt exactly
+# our namespace from tracking in every process that imports this module.
+from multiprocessing import resource_tracker as _rt  # noqa: E402
 
-_HAS_TRACK = "track" in _inspect.signature(SharedMemory.__init__).parameters
 
-if not _HAS_TRACK:
-    # Python < 3.13 has no SharedMemory(track=False): every process that
-    # maps a segment registers it with the resource tracker, which then
-    # unlinks it behind our back (and races other processes' messages into
-    # noisy KeyErrors).  REFT segments must outlive any single process —
-    # that is the whole point of the SMP design — and their lifetime is
-    # managed explicitly via unlink_node(), so exempt exactly our
-    # namespace from tracking in every process that imports this module.
-    from multiprocessing import resource_tracker as _rt
+def _exempt(fn):
+    def wrapped(name, rtype):
+        if rtype == "shared_memory" and str(name).lstrip("/") \
+                .startswith("reft-"):
+            return
+        return fn(name, rtype)
+    return wrapped
 
-    def _exempt(fn):
-        def wrapped(name, rtype):
-            if rtype == "shared_memory" and str(name).lstrip("/") \
-                    .startswith("reft-"):
-                return
-            return fn(name, rtype)
-        return wrapped
 
-    if not getattr(_rt, "_reft_exempt", False):
-        _rt.register = _exempt(_rt.register)
-        _rt.unregister = _exempt(_rt.unregister)
-        _rt._reft_exempt = True
+if not getattr(_rt, "_reft_exempt", False):
+    _rt.register = _exempt(_rt.register)
+    _rt.unregister = _exempt(_rt.unregister)
+    _rt._reft_exempt = True
 
 
 class _Shm(SharedMemory):
     """SharedMemory that never registers with the resource tracker (see
-    above / `track=False` on modern Pythons) and tolerates numpy views
-    still alive at interpreter exit (close is always attempted explicitly
-    first; this only silences the cosmetic late-GC BufferError)."""
-
-    def __init__(self, name=None, create=False, size=0, track=False):
-        if _HAS_TRACK:
-            super().__init__(name=name, create=create, size=size, track=track)
-        else:
-            super().__init__(name=name, create=create, size=size)
+    above) and tolerates numpy views still alive at interpreter exit
+    (close is always attempted explicitly first; this only silences the
+    cosmetic late-GC BufferError)."""
 
     def __del__(self):
         try:
@@ -101,16 +91,16 @@ class _Shm(SharedMemory):
 
 def _create(name: str, size: int) -> SharedMemory:
     try:
-        old = _Shm(name=name, track=False)
+        old = _Shm(name=name)
         old.close()
         old.unlink()
     except FileNotFoundError:
         pass
-    return _Shm(name=name, create=True, size=max(size, 1), track=False)
+    return _Shm(name=name, create=True, size=max(size, 1))
 
 
 def _attach(name: str) -> SharedMemory:
-    return _Shm(name=name, track=False)
+    return _Shm(name=name)
 
 
 @dataclass(frozen=True)
@@ -1016,7 +1006,7 @@ class ReadOnlyNode:
         for what in (["stage", "ctl", "meta"] +
                      [f"buf{i}" for i in range(NBUF)]):
             try:
-                s = _Shm(name=_seg(run, node, what), track=False)
+                s = _Shm(name=_seg(run, node, what))
                 s.close()
                 s.unlink()
             except FileNotFoundError:

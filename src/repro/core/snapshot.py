@@ -4,7 +4,7 @@ Each SG member snapshots (a) its own 1/n byte-shard of the train state and
 (b) the blocks of its parity stripe (XOR-folded in the SMP), in tiny
 buckets, asynchronously with training.
 
-JAX adaptation note (DESIGN.md §2): jax.Arrays are immutable, so holding a
+JAX adaptation note: jax.Arrays are immutable, so holding a
 reference to the step-t state pins a consistent snapshot for free — no
 GPU-side tensor duplication is needed before the async d2h copy, unlike the
 PyTorch original.
@@ -30,8 +30,7 @@ from repro.core import raim5
 from repro.core.delta import DeltaLog, DeltaTracker
 from repro.core.pipeline import (DeltaBaseMismatch, LeafReader,
                                  PipelineFlight, SnapshotPipeline,
-                                 leaf_budget, resolve_affinity,
-                                 resolve_device_encode)
+                                 leaf_budget, resolve_affinity)
 from repro.core.smp import NodeLayout, SMPHandle
 from repro.core.treebytes import FlatSpec, leaf_arrays, make_flat_spec
 
@@ -61,9 +60,8 @@ class ReftConfig:
     yield_every_buckets: int = 4     # L1 yields to training this often
     boundary_timeout_s: float = 0.005  # max wait for a step boundary
     # --- device-side encode + multi-flight (docs/API.md) ---
-    device_encode: str = "auto"      # "auto" (on iff a real accelerator
-                                     # backs JAX) | "on" | "off"
-    crc_impl: str = "pallas"         # device CRC: "pallas" | "jnp" fallback
+    device_encode: str = "auto"      # "auto" (on iff the saved leaves
+                                     # live on an accelerator) | "on" | "off"
     max_flights: int = 1             # >1: snapshot N+1's L1 may overlap
                                      # snapshot N's L2/L3 drain
     pin_cpus: Any = "auto"           # saving-path CPU set for the L2
@@ -84,7 +82,8 @@ class ReftConfig:
                                      # (off: provider ranges only)
     ranged_fetch: str = "auto"       # sparse delta flights d2h only the
                                      # touched leaf extents: "auto" (on iff
-                                     # a real accelerator) | "on" | "off"
+                                     # the leaves live on an accelerator)
+                                     # | "on" | "off"
     # --- straggler-aware loading (docs/API.md "Straggler-aware loading") ---
     restore_sched: str = "adaptive"  # restore read executor: "fcfs"
                                      # (legacy one-thread-per-member) |
@@ -125,7 +124,8 @@ class SnapshotEngine:
         self._pipeline: Optional[SnapshotPipeline] = None
         if cfg.pipeline:
             self._pipeline = SnapshotPipeline(self.smp, self.spec, cfg,
-                                              self._own, self._stripe)
+                                              self._own, self._stripe,
+                                              leaf_arrays(state_template))
         self._max_flights = max(1, int(getattr(cfg, "max_flights", 1))) \
             if cfg.pipeline else 1
         # dirty-delta snapshotting: only meaningful on the pipelined path
@@ -150,6 +150,8 @@ class SnapshotEngine:
         self._thread: Optional[threading.Thread] = None    # serial mode
         self._err: Optional[BaseException] = None
         self.degraded = False      # SMP unreachable: snapshots paused, not fatal
+        self.first_error: Optional[str] = None   # first failed flight, for
+                                                 # train.py's exit report
         # mutable copy of cfg.persist_delay_s: ReftConfig is frozen, but
         # fault injection (slow-persist / slow-NFS scenarios) must be able
         # to raise durable-tier latency mid-run
@@ -170,6 +172,8 @@ class SnapshotEngine:
                       "persist_upload_retries": 0,
                       "device_encode": (self._pipeline.device_encode
                                         if self._pipeline else False),
+                      "ranged_fetch": (self._pipeline.ranged_fetch
+                                       if self._pipeline else False),
                       "stager_affinity": None,
                       "skipped_buckets": 0, "delta_flights": 0,
                       "keyframe_flights": 0, "delta_base_misses": 0}
@@ -337,6 +341,8 @@ class SnapshotEngine:
         flight are lost, so the next flight must be a full keyframe."""
         if self._tracker is not None:
             self._tracker.invalidate()
+        if self.first_error is None:
+            self.first_error = f"{type(e).__name__}: {e}"
         if self._err is None:
             self._err = e
 
@@ -420,7 +426,7 @@ class SnapshotEngine:
             self.stats["l2_seconds"] += l2
             self.stats["l3_seconds"] += l3
         except BaseException as e:                      # surfaced on wait()
-            self._err = e
+            self._flight_failed(e)
 
     # ------------------------------------------------------------ ckpt
     def delta_extents_since(self, base: Optional[int],

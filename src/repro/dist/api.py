@@ -10,13 +10,9 @@ the same model code runs everywhere.
   * axis names the mesh does not have are dropped;
   * an axis (or tuple prefix) only survives if its cumulative size divides
     the corresponding array dimension — GSPMD requires even sharding.
-
-Works on both modern jax (`jax.set_mesh` / abstract meshes) and the
-legacy 0.4.x global-mesh context (`with mesh:`).
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Any, Optional, Sequence
 
 import jax
@@ -28,31 +24,14 @@ def _axis_sizes(mesh) -> dict:
 
 
 def _active_mesh():
-    """The mesh of the enclosing mesh context, or None outside any."""
-    try:                                     # modern jax: jax.set_mesh(...)
-        from jax.sharding import get_abstract_mesh
-        m = get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except ImportError:
-        pass
-    try:                                     # legacy jax: `with mesh:`
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except (ImportError, AttributeError):
-        pass
-    return None
+    """The mesh of the enclosing `jax.set_mesh` context, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def use_mesh(mesh):
-    """Version-portable mesh context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh                              # legacy Mesh is a context manager
+    """Mesh context manager (`jax.set_mesh`)."""
+    return jax.set_mesh(mesh)
 
 
 def adapt_spec(spec: P, shape: Sequence[int], mesh) -> P:
@@ -84,9 +63,9 @@ def adapt_spec(spec: P, shape: Sequence[int], mesh) -> P:
 def shard(x: Any, spec: P) -> Any:
     """Constrain `x` to `spec` on the active mesh (identity without one)."""
     mesh = _active_mesh()
-    if mesh is None:
-        return x
-    sp = adapt_spec(spec, x.shape, mesh)
+    if mesh is None or jax.sharding.AxisType.Manual in mesh.axis_types:
+        return x                     # no mesh, or inside shard_map: the
+    sp = adapt_spec(spec, x.shape, mesh)   # body already holds shards
     if all(e is None for e in sp):
         return x
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, sp))

@@ -7,14 +7,14 @@
 * ssd_scan      — Mamba2 chunked state-space-duality scan
 * swa_attention — banded (sliding-window) flash attention
 
-Each kernel ships <name>.py (pl.pallas_call + BlockSpec), a jit'd wrapper
-in ops.py, and a pure-jnp oracle in ref.py, swept in tests/.
+Each kernel ships <name>.py (pl.pallas_call + BlockSpec), a pure-jnp
+oracle in ref.py, swept in tests/, and — except `stage`, whose entry point
+is `stage.encode_bucket` — a jit'd wrapper in ops.py.
 """
 from repro.kernels.ops import (
-    encode_bucket, ssd_scan, swa_attention, xor_parity_decode,
-    xor_parity_encode,
+    ssd_scan, swa_attention, xor_parity_decode, xor_parity_encode,
 )
-from repro.kernels.stage import bucket_crc
+from repro.kernels.stage import bucket_crc, encode_bucket
 
 __all__ = ["bucket_crc", "encode_bucket", "ssd_scan", "swa_attention",
            "xor_parity_decode", "xor_parity_encode"]

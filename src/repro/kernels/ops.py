@@ -1,8 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-These are the entry points used by the rest of the system; on this CPU
-container they run in interpret mode (kernel body executed in Python),
-on TPU they compile to Mosaic.  Each has a pure-jnp oracle in ref.py.
+These are the entry points used by the rest of the system.  With
+`interpret=None` each kernel follows the placement of its input
+(`repro.kernels.placement`): compiled to Mosaic for an accelerator-
+resident array, interpreted for a CPU-resident one.  Each has a pure-jnp
+oracle in ref.py.
 """
 from __future__ import annotations
 
@@ -11,8 +13,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.placement import runs_interpreted
 from repro.kernels.ssd_scan import ssd_scan as _ssd_scan_kernel
-from repro.kernels.stage import encode_bucket as _encode_bucket_kernel
 from repro.kernels.swa_attention import swa_flash as _swa_flash_kernel
 from repro.kernels.xor_parity import xor_reduce as _xor_reduce_kernel
 
@@ -21,8 +23,10 @@ def xor_parity_encode(blocks, *, interpret: bool = None):
     """XOR parity of k byte blocks. blocks: (k, nbytes) uint8 -> (nbytes,).
 
     Pads to 4-byte lanes (uint32) for the TPU kernel.  `interpret=None`
-    selects interpret mode from the JAX backend (CPU -> interpreted).
+    follows the placement of `blocks`.
     """
+    if interpret is None:
+        interpret = runs_interpreted(blocks)
     blocks = jnp.asarray(blocks)
     assert blocks.dtype == jnp.uint8 and blocks.ndim == 2
     k, n = blocks.shape
@@ -44,29 +48,21 @@ def xor_parity_decode(survivors, parity, *, interpret: bool = None):
     return xor_parity_encode(stack, interpret=interpret)
 
 
-def encode_bucket(blocks, *, nbytes: int, want_crc: bool = True,
-                  interpret: bool = None, crc_impl: str = "pallas",
-                  tile_lanes: int = None):
-    """Fused snapshot-bucket encode (XOR parity fold + CRC32) on device —
-    see `repro.kernels.stage`.  blocks: (k, n_lanes) uint32.  Buckets
-    beyond `stage.MAX_CELL_LANES` tile over a grid and return per-tile
-    digests (fold with `stage.bucket_crc`)."""
-    return _encode_bucket_kernel(blocks, nbytes=nbytes, want_crc=want_crc,
-                                 interpret=interpret, crc_impl=crc_impl,
-                                 tile_lanes=tile_lanes)
-
-
 def ssd_scan(u, a, Bm, Cm, h0=None, *, chunk: int = 128,
-             interpret: bool = True):
+             interpret: bool = None):
     """Chunked SSD (Mamba2). Same contract as models.ssm.ssd_chunked."""
+    if interpret is None:
+        interpret = runs_interpreted(u)
     return _ssd_scan_kernel(u, a, Bm, Cm, h0, chunk=chunk,
                             interpret=interpret)
 
 
 def swa_attention(q, k, v, *, window=None, causal: bool = True,
                   block_q: int = 128, block_k: int = 128,
-                  interpret: bool = True):
+                  interpret: bool = None):
     """Banded flash attention; window is a *static* int (None = full)."""
+    if interpret is None:
+        interpret = runs_interpreted(q)
     return _swa_flash_kernel(q, k, v, window=window, causal=causal,
                              block_q=block_q, block_k=block_k,
                              interpret=interpret)

@@ -1,19 +1,18 @@
 """RAIM5 XOR parity as a Pallas TPU kernel.
 
-The paper computes parity "byte-wise on the CPU"; the beyond-paper variant
-encodes parity *on the accelerator before the d2h copy*, so the host
-receives shard + parity in one stream and the XOR rides the idle MXU-free
-VPU cycles.  Lanes are uint32 (TPU-native integer width); tiles are
-(8, 128)-aligned VMEM blocks.
+The paper computes parity "byte-wise on the CPU"; the device encode path
+folds parity *on the accelerator before the d2h copy*, so the host
+receives shard + parity in one stream and the XOR rides otherwise idle
+VPU cycles.  Lanes are uint32 (the TPU's native integer width) laid out
+lane-dense as (rows, 128) blocks.
 
 encode: parity[t] = XOR_i blocks[i, t]      blocks: (k, n) uint32
 decode: missing   = XOR(survivors, parity)  == encode on (k, n) stacked
 
-`interpret=None` (the default) selects interpret mode from the JAX
-backend: compiled on a real accelerator, interpreted on CPU (CI).  A
-lane count that does not divide into whole tiles is zero-padded up to a
-128-lane multiple (XOR identity), never ground down to one-element grid
-cells.
+`interpret=None` (the default) follows the input's placement
+(`repro.kernels.placement`): compiled for an accelerator-resident array,
+interpreted for a CPU-resident one.  A lane count that does not fill whole
+(8, 128) tiles is zero-padded (the XOR identity).
 """
 from __future__ import annotations
 
@@ -22,6 +21,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.placement import runs_interpreted
+
+LANES = 128
 
 
 def _xor_kernel(blocks_ref, out_ref):
@@ -33,25 +36,31 @@ def _xor_kernel(blocks_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_elems", "interpret"))
+def _xor_reduce(blocks, *, block_elems: int, interpret: bool):
+    k, n = blocks.shape
+    rows = -(-n // LANES)
+    # block height: a multiple of 8 sublanes, never taller than the data
+    br = max(8, min(block_elems // LANES // 8 * 8, -(-rows // 8) * 8))
+    rows_pad = -(-rows // br) * br
+    n_pad = rows_pad * LANES
+    if n_pad != n:
+        blocks = jnp.pad(blocks, ((0, 0), (0, n_pad - n)))
+    out = pl.pallas_call(
+        _xor_kernel,
+        grid=(rows_pad // br,),
+        in_specs=[pl.BlockSpec((k, br, LANES), lambda i: (0, i, 0))],
+        out_specs=pl.BlockSpec((br, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows_pad, LANES), jnp.uint32),
+        interpret=interpret,
+        name="xor_reduce",
+    )(blocks.reshape(k, rows_pad, LANES)).reshape(-1)
+    return out[:n] if n_pad != n else out
+
+
 def xor_reduce(blocks: jax.Array, *, block_elems: int = 64 * 1024,
                interpret: bool = None) -> jax.Array:
     """XOR-reduce along axis 0. blocks: (k, n) uint32 -> (n,) uint32."""
+    assert blocks.dtype == jnp.uint32 and blocks.ndim == 2
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    k, n = blocks.shape
-    assert blocks.dtype == jnp.uint32
-    # tile size: a whole number of 128-lane groups, never below one tile
-    be = max(128, min(block_elems // 128 * 128, -(-n // 128) * 128))
-    n_pad = -(-n // be) * be                 # pad up (zeros = XOR identity)
-    if n_pad != n:
-        blocks = jnp.pad(blocks, ((0, 0), (0, n_pad - n)))
-    grid = (n_pad // be,)
-    out = pl.pallas_call(
-        _xor_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((k, be), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((be,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.uint32),
-        interpret=interpret,
-    )(blocks)
-    return out[:n] if n_pad != n else out
+        interpret = runs_interpreted(blocks)
+    return _xor_reduce(blocks, block_elems=block_elems, interpret=interpret)

@@ -58,12 +58,8 @@ def collective_bytes(hlo_text: str) -> dict:
 
 
 def cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict on modern jax, a one-element
-    list of dicts on 0.4.x — normalize to the dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """compiled.cost_analysis() as a dict (empty when unavailable)."""
+    return compiled.cost_analysis() or {}
 
 
 def _abstract_state(cfg):

@@ -9,11 +9,8 @@ import jax
 
 
 def _mesh(shape, axes):
-    # axis_types only exists on newer jax; older versions default to Auto
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is not None:
-        return jax.make_mesh(shape, axes, axis_types=(at.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,7 +26,7 @@ def make_smoke_mesh():
     return _mesh((n, 1), ("data", "model"))
 
 
-# v5e hardware constants for the roofline (DESIGN.md §6)
+# v5e hardware constants for the roofline (Google Cloud, "TPU v5e")
 PEAK_FLOPS_BF16 = 197e12          # per chip
 HBM_BW = 819e9                    # bytes/s per chip
 ICI_BW = 50e9                     # bytes/s per link

@@ -15,11 +15,18 @@ Elastic restart (reshard-on-restore): `--resume` works with a DIFFERENT
 `--sg-size` than the run that wrote the checkpoint — the distributed
 loader rediscovers the saved layout from the REFT-Ckpt family heads and
 ranges its reads accordingly, so an n-node run restores onto m nodes.
+
+A member whose saving sidecar is still degraded when the run ends makes
+`main` print that member's first flight error and exit 1: training
+rides through a lost sidecar, but the run does not report success
+without the protection it was asked for.
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import time
+from typing import Callable, Optional
 
 import numpy as np
 
@@ -41,7 +48,14 @@ def _load_stats_str(ld) -> str:
     return out
 
 
-def main(argv=None):
+def main(argv=None, *, observe: Optional[Callable[..., None]] = None):
+    """Train; returns the process exit code.
+
+    `observe(event, **info)`, when given, is called inside the session:
+    "step" after each `after_step` (step, state, loss, did, sess),
+    "recovered" after each injected failure's restore (res, sess), and
+    "end" after the final drain (sess).  `chip_smoke.py` checks the run
+    through it."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="opt-125m")
     ap.add_argument("--reduced", action="store_true")
@@ -79,6 +93,8 @@ def main(argv=None):
     if args.no_reft:
         args.backend = "null"
 
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     from repro.api import CheckpointSession, CheckpointSpec
     from repro.core.recovery import RecoveryError
     from repro.configs import get_config
@@ -154,7 +170,10 @@ def main(argv=None):
             state, metrics = step_fn(state, batch)
             step = int(state["step"])
             losses.append(float(metrics["loss"]))
-            sess.after_step(state, step, extra_meta=ds.state())
+            did = sess.after_step(state, step, extra_meta=ds.state())
+            if observe is not None:
+                observe("step", step=step, state=state, loss=losses[-1],
+                        did=did, sess=sess)
 
             if step in injections:
                 sc = injections.pop(step)
@@ -190,6 +209,8 @@ def main(argv=None):
                              f"lower --snapshot-every or inject later)")
                 print(f"[recover] tier={res.tier} step={res.step}"
                       + _load_stats_str(res.load))
+                if observe is not None:
+                    observe("recovered", res=res, sess=sess)
                 state = jax.tree.map(jnp.asarray, res.state)
                 ds.restore(res.extra_meta)
                 step = res.step
@@ -199,6 +220,9 @@ def main(argv=None):
                       f"({(time.time()-t0)/max(step,1):.2f}s/step)",
                       flush=True)
         sess.drain()               # join async persists + collect events
+        if observe is not None:
+            observe("end", sess=sess)
+        health = sess.health()
         st = sess.stats()
         # engine-side timing when the backend exposes it (async launches
         # make the trainer-side snapshot_seconds near-zero by design)
@@ -210,7 +234,7 @@ def main(argv=None):
               f"persist_overlap_s={st.get('persist_overlap_seconds', 0.0):.3f} "
               f"restores={st.get('restore', 0)} "
               f"avg_snapshot_s={secs/max(snaps, 1):.3f} "
-              f"degraded={sess.degraded}")
+              f"degraded={health['degraded']}")
         if st.get("persist_upload_bytes"):
             print(f"[{args.backend}] uploads="
                   f"{st['persist_upload_bytes'] / 1e6:.1f}MB "
@@ -229,17 +253,22 @@ def main(argv=None):
                   f"families={st.get('scrub_families', 0)} "
                   f"corrupt={st.get('scrub_corrupt', 0)} "
                   f"repaired={st.get('scrub_repaired', 0)}")
+    for node in health["degraded"]:
+        err = health["members"].get(node, {}).get("error")
+        print(f"[degraded] member {node} lost its saving sidecar: "
+              f"{err or 'SMP not alive'}", file=sys.stderr)
+    rc = 1 if health["degraded"] else 0
     if not losses:
         print(f"[done] steps={step} (resumed past --steps; nothing to run) "
               f"wall={time.time()-t0:.1f}s")
-        return 0
+        return rc
     print(f"[done] steps={step} final_loss={losses[-1]:.4f} "
           f"first_loss={losses[0]:.4f} wall={time.time()-t0:.1f}s")
     assert np.isfinite(losses).all(), "loss diverged"
     if args.steps >= 100:                 # short smoke runs are too noisy
         assert np.mean(losses[-10:]) < np.mean(losses[:10]), \
             "loss did not decrease"
-    return 0
+    return rc
 
 
 if __name__ == "__main__":

@@ -234,17 +234,11 @@ def moe_ffn_ep(p, cfg, x):
 
     x_spec = P(dp_axes if dp_axes else None, None, None)
     w_spec = P("model", fsdp if fsdp else None, None)
-    if hasattr(jax, "shard_map"):                      # modern jax
-        smap = jax.shard_map
-        kw = {"check_vma": False}
-    else:                                              # 0.4.x spelling
-        from jax.experimental.shard_map import shard_map as smap
-        kw = {"check_rep": False}
-    y, aux = smap(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
         out_specs=(x_spec, P()),
-        **kw,
+        check_vma=False,
     )(x, p["router"], p["wi_gate"], p["wi_up"], p["wo"])
     return y, aux
 

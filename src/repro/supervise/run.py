@@ -85,6 +85,8 @@ def main(argv=None):
                     help="fail the run under this goodput fraction")
     args = ap.parse_args(argv)
 
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     import jax
     import jax.numpy as jnp
 

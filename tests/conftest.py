@@ -20,16 +20,11 @@ if os.environ.get("ANALYZE_LOCKGRAPH", "") not in ("", "0"):
 
     _LG_TRACER = _lockgraph.install()
 
-try:
-    from hypothesis import settings
-except ModuleNotFoundError:
-    # hypothesis is optional: property tests are skipped without it
-    collect_ignore = ["test_treebytes.py", "test_policy.py",
-                      "test_sharding_plan.py", "test_raim5.py"]
-else:
-    settings.register_profile("ci", deadline=None, max_examples=25,
-                              derandomize=True)
-    settings.load_profile("ci")
+from hypothesis import settings  # noqa: E402
+
+settings.register_profile("ci", deadline=None, max_examples=25,
+                          derandomize=True)
+settings.load_profile("ci")
 
 if _LG_TRACER is not None:
     import pytest

@@ -62,3 +62,39 @@ def test_smp_only_crash_keeps_training(cluster):
     c.kill_smp(3)
     c.run_rounds(2)                          # rounds still complete
     assert all(np_.last_step == 4 for np_ in c.nodes.values())
+
+
+NO_BACKEND = """
+import os
+if __name__ == "__main__":
+    import jax
+    jax.devices()                            # this process holds the backend
+    os.environ["JAX_PLATFORMS"] = "nosuchplatform"   # every child inherits
+    from repro.core.cluster import LocalCluster
+    c = LocalCluster(2, seed=1, nbytes=1 << 14, snapshot_every=1,
+                     ckpt_dir={ckpt!r})
+    try:
+        c.run_rounds(3)
+        c.checkpoint()
+        c.kill_trainer(1)
+        state, step, tier = c.recover()
+    finally:
+        c.close()
+    print("CHILDREN_OK", tier, step)
+"""
+
+
+def test_spawned_children_initialise_no_jax_backend(tmp_path):
+    """Node processes and their SMPs run with a platform that cannot be
+    initialised: any backend init in a child (the chip held twice, on a
+    TPU host) would kill that child and stall the rounds."""
+    import os
+    import subprocess
+    import sys
+    script = tmp_path / "nobackend.py"      # spawn re-imports __main__
+    script.write_text(NO_BACKEND.format(ckpt=str(tmp_path / "ck")))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(
+        os.path.dirname(__file__), "..", "src"))
+    out = subprocess.run([sys.executable, str(script)], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert "CHILDREN_OK in-memory 3" in out.stdout, out.stderr[-3000:]

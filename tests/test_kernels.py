@@ -28,10 +28,9 @@ def test_xor_reduce_sweep(k, n):
 
 @pytest.mark.parametrize("n", [1, 7, 127, 129, 255, 4097])
 def test_xor_reduce_odd_sizes_padded_tile(n):
-    """Satellite fix: an odd lane count degrades to a zero-padded
-    128-lane tile, not a be=1 one-element-per-grid-cell grind (and the
-    interpret default now comes from the JAX backend — no explicit
-    flag here)."""
+    """An odd lane count is zero-padded to whole (8, 128) tiles, not
+    ground down to one-element grid cells (and the interpret default
+    follows the input's placement — no explicit flag here)."""
     rng = np.random.default_rng(n)
     blocks = jnp.asarray(
         rng.integers(0, 2 ** 32, size=(3, n), dtype=np.uint64)
@@ -42,22 +41,34 @@ def test_xor_reduce_odd_sizes_padded_tile(n):
 
 
 # ----------------------------------------------------- stage encode kernel
-@pytest.mark.parametrize("crc_impl", ["pallas", "jnp"])
+def _bucket(k, nbytes, seed):
+    """(k, n_lanes) uint32 stripe blocks whose bytes past `nbytes` are the
+    zero padding the device gather leaves there."""
+    from repro.kernels.stage import LANE_BYTES
+    rng = np.random.default_rng(seed)
+    npad = -(-nbytes // LANE_BYTES) * LANE_BYTES
+    data = np.zeros((k, npad), np.uint8)
+    data[:, :nbytes] = rng.integers(0, 256, (k, nbytes), dtype=np.uint8)
+    return data.view(np.uint32)
+
+
+@pytest.mark.parametrize("k", [1, 3])
 @pytest.mark.parametrize("nbytes", [4, 5, 7, 100, 1001, 4096])
-def test_encode_bucket_crc_matches_zlib(crc_impl, nbytes):
-    rng = np.random.default_rng(nbytes)
-    npad = -(-nbytes // 512) * 512
-    data = np.zeros(npad, np.uint8)
-    data[:nbytes] = rng.integers(0, 256, nbytes, dtype=np.uint8)
-    lanes = jax.lax.bitcast_convert_type(
-        jnp.asarray(data).reshape(-1, 4), jnp.uint32).reshape(1, -1)
-    out, crc = encode_bucket(lanes, nbytes=nbytes, crc_impl=crc_impl)
-    assert int(crc[0]) == zlib.crc32(data[:nbytes].tobytes())
-    assert np.array_equal(np.asarray(out).view(np.uint8), data)
+def test_encode_bucket_crc_matches_zlib(k, nbytes):
+    """Own-data buckets (k=1) and checksummed parity folds (k=3, the
+    delta path's digest compare) agree with zlib at every tail length."""
+    from repro.kernels.stage import bucket_crc
+    blocks = _bucket(k, nbytes, nbytes)
+    ref = np.bitwise_xor.reduce(blocks, axis=0)
+    out, crc = encode_bucket(jnp.asarray(blocks), nbytes=nbytes)
+    assert bucket_crc(crc, nbytes) == zlib.crc32(
+        ref.view(np.uint8)[:nbytes].tobytes())
+    assert np.array_equal(np.asarray(out), ref)
 
 
 @pytest.mark.parametrize("k", [2, 3, 5])
 def test_encode_bucket_xor_fold_matches_ref(k):
+    from repro.kernels.stage import bucket_crc
     rng = np.random.default_rng(k)
     blocks = rng.integers(0, 2 ** 32, (k, 256), dtype=np.uint64) \
         .astype(np.uint32)
@@ -65,8 +76,8 @@ def test_encode_bucket_xor_fold_matches_ref(k):
                              want_crc=True)
     ref, ref_crc = encode_bucket_ref(blocks, 1024)
     assert np.array_equal(np.asarray(out), ref)
-    assert int(crc[0]) == ref_crc
-    # parity callers skip the (sequential) CRC
+    assert bucket_crc(crc, 1024) == ref_crc
+    # parity callers skip the CRC
     out2, crc2 = encode_bucket(jnp.asarray(blocks), nbytes=1024,
                                want_crc=False)
     assert np.array_equal(np.asarray(out2), ref)
@@ -75,38 +86,30 @@ def test_encode_bucket_xor_fold_matches_ref(k):
 
 @pytest.mark.parametrize("nbytes", [(1 << 20) + 13, 4 << 20])
 def test_encode_bucket_tiled_large_matches_zlib(nbytes):
-    """Satellite: buckets past MAX_CELL_LANES tile over a grid (each cell
-    checksums only its slice) and the per-tile digests recombine via
-    crc32_combine into exactly zlib's answer."""
-    from repro.kernels.stage import (LANE_BYTES, MAX_CELL_LANES, bucket_crc,
-                                     resolve_tile_lanes)
-    rng = np.random.default_rng(nbytes)
-    npad = -(-nbytes // LANE_BYTES) * LANE_BYTES
-    data = np.zeros(npad, np.uint8)
-    data[:nbytes] = rng.integers(0, 256, nbytes, dtype=np.uint8)
-    lanes = jax.lax.bitcast_convert_type(
-        jnp.asarray(data).reshape(-1, 4), jnp.uint32).reshape(1, -1)
-    assert lanes.shape[1] > MAX_CELL_LANES          # really tiled
-    assert resolve_tile_lanes(lanes.shape[1]) is not None
-    out, crc = encode_bucket(lanes, nbytes=nbytes)
-    assert np.asarray(crc).size > 1                 # per-tile digests
+    """Buckets past one grid cell run the CRC over a grid of cells that
+    carry the 1024 lane registers between them; the chunk digests fold
+    into exactly zlib's answer, and the digests do not depend on how the
+    word axis is tiled."""
+    from repro.kernels.stage import bucket_crc, crc_cells, encode_bucket
+    blocks = _bucket(1, nbytes, nbytes)
+    data = blocks.view(np.uint8).reshape(-1)
+    assert crc_cells(nbytes) > 1                    # really tiled
+    out, crc = encode_bucket(jnp.asarray(blocks), nbytes=nbytes)
+    assert np.asarray(crc).size == 1024             # one digest per lane
     assert bucket_crc(crc, nbytes) == zlib.crc32(data[:nbytes].tobytes())
     assert np.array_equal(np.asarray(out).view(np.uint8), data)
-    # explicit tile width: same answer through a different tiling
-    out2, crc2 = encode_bucket(lanes, nbytes=nbytes, tile_lanes=1 << 14)
-    assert np.asarray(crc2).size != np.asarray(crc).size
-    assert bucket_crc(crc2, nbytes, tile_lanes=1 << 14) \
-        == zlib.crc32(data[:nbytes].tobytes())
-    # folding an explicit tiling WITHOUT tile_lanes must refuse, not
-    # silently combine wrong per-part lengths
-    with pytest.raises(AssertionError):
-        bucket_crc(crc2, nbytes)
+    # another tiling of the word axis: identical digests
+    assert crc_cells(nbytes, cell_words=48) != crc_cells(nbytes)
+    out2, crc2 = encode_bucket(jnp.asarray(blocks), nbytes=nbytes,
+                               cell_words=48)
+    assert np.array_equal(np.asarray(crc2), np.asarray(crc))
 
 
 def test_encode_bucket_tiled_xor_fold():
-    from repro.kernels.stage import bucket_crc
+    from repro.kernels.stage import bucket_crc, crc_cells
     rng = np.random.default_rng(3)
-    k, n = 3, 1 << 17                               # > MAX_CELL_LANES
+    k, n = 3, 1 << 19                               # 2 MiB: 2 grid cells
+    assert crc_cells(4 * n) > 1
     blocks = rng.integers(0, 2 ** 32, (k, n), dtype=np.uint64) \
         .astype(np.uint32)
     out, crc = encode_bucket(jnp.asarray(blocks), nbytes=4 * n)

@@ -20,9 +20,8 @@ SCRIPT = textwrap.dedent("""
     cfg = get_config("dbrx-132b").reduced()
     p = init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model))
-    at = getattr(jax.sharding, "AxisType", None)
-    kw = {"axis_types": (at.Auto,) * 2} if at is not None else {}
-    mesh = jax.make_mesh((2, 4), ("data", "model"), **kw)
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     y_ref, _ = moe_ffn_gspmd(p, cfg, x)
     with use_mesh(mesh):
         y_ep, _ = jax.jit(lambda p, x: moe_ffn_ep(p, cfg, x))(p, x)
