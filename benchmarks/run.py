@@ -20,8 +20,6 @@ SECTIONS = [
     ("optimal intervals (Appx. A)", "benchmarks.intervals"),
     ("failure-scenario sweep + survival (Fig. 8)",
      "benchmarks.failure_sweep"),
-    ("kernels", "benchmarks.kernels_bench"),
-    ("roofline (dry-run)", "benchmarks.roofline"),
 ]
 
 

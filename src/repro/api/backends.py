@@ -188,8 +188,21 @@ class ReftCheckpointer(Checkpointer):
             self.emit("snapshot", step, seconds=time.perf_counter() - t0,
                       nbytes=self.group.total_bytes, levels=levels,
                       detail="" if wait else "async-launch")
+        self._emit_published()
         self._check_degraded(step)
         return started
+
+    def _emit_published(self) -> None:
+        """One `snapshot-published` event per member flight collected
+        since the last call (engines collect flights as they launch the
+        next one, and on every wait)."""
+        for e in self.group.engines:
+            while e.published:
+                r = e.published.popleft()
+                self.emit("snapshot-published", r.step,
+                          seconds=r.wall_seconds, nbytes=r.bytes_sent,
+                          levels=r.levels(), detail=f"node{e.node}",
+                          t_start=r.t_start, t_published=r.t_published)
 
     def set_dirty_provider(self, fn) -> None:
         """Install the delta saving path's dirtiness signal on every
@@ -255,6 +268,7 @@ class ReftCheckpointer(Checkpointer):
         self.poll_persists()
         if wait:
             self.group.wait()          # capture the newest snapshot
+            self._emit_published()
         s = self.group.checkpoint_async(remote=self._persist_remote(),
                                         delta_base=self._delta_base())
         if s is None:
@@ -312,6 +326,7 @@ class ReftCheckpointer(Checkpointer):
                 e.wait()
             except Exception:
                 e.degraded = True
+        self._emit_published()
         # a degraded member's SMP is gone: its segments (if any survive)
         # hold STALE steps that would drag the common step backwards —
         # treat it like a failed node and let RAIM5 repair it instead
@@ -505,6 +520,7 @@ class ReftCheckpointer(Checkpointer):
 
     def wait(self):
         self.group.wait()
+        self._emit_published()
         self._emit_rounds(self.group.drain_persists())
 
     def close(self):

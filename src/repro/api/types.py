@@ -30,19 +30,24 @@ from typing import Any, Callable, Dict, Optional
 @dataclass(frozen=True)
 class CkptEvent:
     """One structured record per checkpointing operation."""
-    kind: str                     # snapshot | persist | persist-error |
-                                  # restore | degraded | inject | heal | gc
+    kind: str                     # snapshot | snapshot-published |
+                                  # persist | persist-error | restore |
+                                  # degraded | inject | heal | gc
     step: int
     backend: str
     seconds: float = 0.0
     nbytes: int = 0
     tier: Optional[str] = None    # restore only: in-memory | raim5 | ...
     detail: str = ""
-    # saving-pipeline decomposition for this operation (seconds spent per
-    # HASC level: l1 device reads / l1_stall credit waits / l2 ring writes
-    # / l3 SMP signaling+ack); None for backends without a pipeline
+    # saving-pipeline decomposition for this operation (seconds per HASC
+    # level, the keys of `repro.core.pipeline.LEVELS`); None for backends
+    # without a pipeline
     levels: Optional[Dict[str, float]] = None
     wall: float = field(default_factory=time.time)
+    # snapshot-published only (one per member flight): the flight's start
+    # and publish times on the `time.perf_counter` clock
+    t_start: Optional[float] = None
+    t_published: Optional[float] = None
 
 
 @dataclass(frozen=True)

@@ -16,6 +16,7 @@ import os
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from repro.core.pipeline import LEVELS
 from repro.core.policy import FrequencyPlan, plan_frequencies
 from repro.core.recovery import (
     RecoveryError, restore_from_checkpoint, restore_state,
@@ -86,15 +87,9 @@ class ReftGroup:
 
     def level_seconds(self) -> Dict[str, float]:
         """Aggregate per-level pipeline timing across members (HASC):
-        l1 = device reads (+stall = scratch-credit waits), l2 = staging
-        ring writes, l3 = SMP signaling + clean-ack."""
-        out = {"l1": 0.0, "l1_stall": 0.0, "l2": 0.0, "l3": 0.0}
-        for e in self.engines:
-            out["l1"] += e.stats.get("l1_seconds", 0.0)
-            out["l1_stall"] += e.stats.get("l1_stall_seconds", 0.0)
-            out["l2"] += e.stats.get("l2_seconds", 0.0)
-            out["l3"] += e.stats.get("l3_seconds", 0.0)
-        return out
+        the keys of `repro.core.pipeline.LEVELS`."""
+        return {k: sum(e.stats.get(f"{k}_seconds", 0.0)
+                       for e in self.engines) for k in LEVELS}
 
     def checkpoint_async(self, remote: Optional[dict] = None,
                          delta_base: Optional[int] = None

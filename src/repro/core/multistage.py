@@ -22,6 +22,7 @@ import numpy as np
 import jax
 
 from repro.core.coordinator import NodeState, ReftGroup
+from repro.core.pipeline import LEVELS
 from repro.core.snapshot import ReftConfig
 from repro.core.treebytes import (buffer_to_tree, make_flat_spec,
                                   tree_to_buffer)
@@ -99,7 +100,7 @@ class MultiStageGroup:
         return min(steps) if steps else -1
 
     def level_seconds(self) -> Dict[str, float]:
-        out = {"l1": 0.0, "l1_stall": 0.0, "l2": 0.0, "l3": 0.0}
+        out = dict.fromkeys(LEVELS, 0.0)
         for g in self.groups:
             for k, v in g.level_seconds().items():
                 out[k] += v

@@ -59,11 +59,13 @@ import numpy as np
 from repro.analyze.lockgraph import named_condition
 from repro.core.crcutil import crc32_concat
 from repro.core.delta import FlightDelta, merge_ranges, task_dirty
+from repro.core.spans import span
 from repro.core.treebytes import FlatSpec, iter_buckets
 
 __all__ = [
     "StepBoundaryGate", "step_boundary", "BucketTask", "build_schedule",
-    "leaf_budget", "leaf_extents", "LeafReader", "DeviceEncoder", "PipelineResult",
+    "leaf_budget", "leaf_extents", "LeafReader", "DeviceEncoder", "LEVELS",
+    "PipelineResult",
     "PipelineFlight", "SnapshotPipeline", "resolve_device_encode",
     "resolve_ranged_fetch",
     "resolve_affinity", "pin_current_thread", "task_local_extent",
@@ -531,22 +533,48 @@ class DeviceEncoder:
 
 
 # --------------------------------------------------------------- flights
+# the per-level seconds a flight reports (`PipelineResult.levels`), and
+# the spans that time them (`repro.core.spans`):
+#   l1_dispatch  repro.hasc.l1.dispatch   encode/gather dispatch and the
+#                                         d2h start (host path: the fetch)
+#   l1_d2h       repro.hasc.l1.d2h        waits for digests and payloads
+#                                         (host path: the reads)
+#   l1           l1_dispatch + l1_d2h
+#   l1_gate      repro.hasc.l1.gate       yields to a training step boundary
+#   l1_stall     repro.hasc.l1.credit     waits for a scratch-buffer credit
+#   l2           repro.hasc.l2.send       staging-ring writes incl. slot
+#                                         waits (+ host digests)
+#   l3           repro.hasc.l3.begin,     begin, end, the SMP's clean-ack
+#                repro.hasc.l3.publish
+LEVELS = ("l1", "l1_stall", "l2", "l3", "l1_dispatch", "l1_d2h", "l1_gate")
+
+
 @dataclass(frozen=True)
 class PipelineResult:
-    """Per-flight outcome with the per-level timing decomposition."""
+    """Per-flight outcome with the per-level timing decomposition
+    (`LEVELS`) and the flight's start and publish times on the
+    `time.perf_counter` clock."""
     step: int
     clean_step: int
     bytes_sent: int
-    l1_seconds: float            # device->host reads (+ prefetch issue)
-    l1_stall_seconds: float      # waiting for a scratch-buffer credit
-    l2_seconds: float            # staging-ring writes incl. slot waits
-    l3_seconds: float            # begin/end signaling + SMP clean-ack
+    l1_seconds: float
+    l1_stall_seconds: float
+    l2_seconds: float
+    l3_seconds: float
     wall_seconds: float
+    l1_dispatch_seconds: float
+    l1_d2h_seconds: float
+    l1_gate_seconds: float
+    t_start: float
+    t_published: float
     # ---- dirty-delta bookkeeping (delta-enabled pipelines only)
     skipped_buckets: int = 0     # buckets never sent (provider or digest)
     delta_base: Optional[int] = None    # base step of a delta flight
     digests: Optional[Dict[int, int]] = None   # task idx -> bucket CRC32
     sent_extents: Tuple[Tuple[int, int], ...] = ()   # buffer-local, merged
+
+    def levels(self) -> Dict[str, float]:
+        return {k: getattr(self, f"{k}_seconds") for k in LEVELS}
 
 
 _STOP = object()
@@ -595,8 +623,9 @@ class PipelineFlight:
         self._draining = threading.Event()
         self._free = free                       # SHARED scratch-credit pool
         self._ready: "queue.Queue" = queue.Queue()
-        self._l1_read = 0.0
-        self._l1_stall = 0.0
+        # seconds by level; the pump adds to the l1 keys, the stager to l2
+        # and l3 (each key has one writer)
+        self._sec = {k: 0.0 for k in LEVELS if k != "l1"}
         self._t0 = time.perf_counter()
         self._pump_t = threading.Thread(target=self._pump, daemon=True,
                                         name=f"hasc-l1-s{step}")
@@ -610,16 +639,21 @@ class PipelineFlight:
 
     # ------------------------------------------------------------- L1
     def _get_credit(self):
-        while True:
-            try:
-                t0 = time.perf_counter()
-                buf = self._free.get(timeout=0.5)
-                self._l1_stall += time.perf_counter() - t0
-                return buf
-            except queue.Empty:
-                self._l1_stall += 0.5
-                if self._abort.is_set():
-                    raise RuntimeError("snapshot pipeline aborted") from None
+        with span("repro.hasc.l1.credit", self._sec, "l1_stall"):
+            while True:
+                try:
+                    return self._free.get(timeout=0.5)
+                except queue.Empty:
+                    if self._abort.is_set():
+                        raise RuntimeError(
+                            "snapshot pipeline aborted") from None
+
+    def _yield(self, w: int, every: int, timeout: float) -> None:
+        """Every `every` buckets, wait for the next training step boundary
+        (not while a caller drains the flight: no step comes then)."""
+        if every and w and w % every == 0 and not self._draining.is_set():
+            with span("repro.hasc.l1.gate", self._sec, "l1_gate"):
+                GATE.wait_boundary(timeout)
 
     def _wait_event(self, ev: threading.Event, what: str) -> None:
         while not ev.wait(0.5):
@@ -690,7 +724,6 @@ class PipelineFlight:
         for w, (i, task) in enumerate(work):
             if self._abort.is_set():
                 raise RuntimeError("snapshot pipeline aborted")
-            t0 = time.perf_counter()
             fresh = []
             for _, nxt in work[w:w + window]:      # windowed prefetch
                 spans = [(nxt.leaf_lo, nxt.leaf_hi)]
@@ -706,32 +739,31 @@ class PipelineFlight:
                         if li not in issued:
                             issued.add(li)
                             fresh.append(li)
-            if fresh:
-                reader.fetch(fresh)     # one batched d2h for the window
-            self._l1_read += time.perf_counter() - t0
-            if yield_every and w and w % yield_every == 0 \
-                    and not self._draining.is_set():
-                GATE.wait_boundary(yield_timeout)  # yield to training
+            if fresh:                   # one batched d2h for the window
+                with span("repro.hasc.l1.dispatch", self._sec,
+                          "l1_dispatch"):
+                    reader.fetch(fresh)
+            self._yield(w, yield_every, yield_timeout)
             buf = self._get_credit()
             nb = task.hi - task.lo
-            t0 = time.perf_counter()
             try:
-                if task.kind == 2 and task.sources:
-                    # host-side fused parity: fold the n-1 stripe source
-                    # ranges so the ring carries ONE pre-encoded block
-                    reader.read(task.sources[0][0], task.sources[0][1],
-                                buf[:nb])
-                    if fold is None:
-                        fold = np.empty(self.cfg.bucket_bytes, np.uint8)
-                    for lo, hi in task.sources[1:]:
-                        reader.read(lo, hi, fold[:nb])
-                        np.bitwise_xor(buf[:nb], fold[:nb], out=buf[:nb])
-                else:
-                    reader.read(task.lo, task.hi, buf[:nb])
+                with span("repro.hasc.l1.d2h", self._sec, "l1_d2h"):
+                    if task.kind == 2 and task.sources:
+                        # host-side fused parity: fold the n-1 stripe
+                        # source ranges so the ring carries ONE
+                        # pre-encoded block
+                        reader.read(task.sources[0][0], task.sources[0][1],
+                                    buf[:nb])
+                        if fold is None:
+                            fold = np.empty(self.cfg.bucket_bytes, np.uint8)
+                        for lo, hi in task.sources[1:]:
+                            reader.read(lo, hi, fold[:nb])
+                            np.bitwise_xor(buf[:nb], fold[:nb], out=buf[:nb])
+                    else:
+                        reader.read(task.lo, task.hi, buf[:nb])
             except BaseException:
                 self._free.put(buf)                # never leak a credit
                 raise
-            self._l1_read += time.perf_counter() - t0
             # host digests (and the digest-compare skip) run in the L2
             # stager, not here: L1 is the device-read level and stays
             # read-only — the device path keeps CRC on the accelerator
@@ -753,42 +785,37 @@ class PipelineFlight:
         for w, (i, task) in enumerate(work):
             if self._abort.is_set():
                 raise RuntimeError("snapshot pipeline aborted")
-            t0 = time.perf_counter()
             for x in range(w, min(w + window, len(work))):
                 j, tj = work[x]
                 if j not in pending:       # encode a window ahead; the
-                    pending[j] = enc.encode(  # kernels + d2h run async
-                        tj, want_crc=True if digesting else None,
-                        prewarm_payload=not defer)
-            self._l1_read += time.perf_counter() - t0   # under this loop
-            if yield_every and w and w % yield_every == 0 \
-                    and not self._draining.is_set():
-                GATE.wait_boundary(yield_timeout)
+                    with span("repro.hasc.l1.dispatch", self._sec,
+                              "l1_dispatch"):  # kernels + d2h run async
+                        pending[j] = enc.encode(
+                            tj, want_crc=True if digesting else None,
+                            prewarm_payload=not defer)
+            self._yield(w, yield_every, yield_timeout)
             lanes, crc, nb = pending.pop(i)
-            t0 = time.perf_counter()
-            crc_val = enc.bucket_crc(np.asarray(crc), nb) \
-                if digesting or task.kind == 0 else None
+            crc_val = None
+            if digesting or task.kind == 0:
+                with span("repro.hasc.l1.d2h", self._sec, "l1_d2h"):
+                    crc_val = enc.bucket_crc(np.asarray(crc), nb)
             if digesting:
                 self._digests[i] = crc_val
             if defer and delta.prev.get(i) == crc_val:
                 self._skipped += 1         # clean: only the digest d2h'd
-                self._l1_read += time.perf_counter() - t0
                 continue
-            self._l1_read += time.perf_counter() - t0
             buf = self._get_credit()       # token: bounds queued buckets
-            t0 = time.perf_counter()
             try:
                 if defer:                  # dirty after all: warm it now
-                    try:
+                    with span("repro.hasc.l1.dispatch", self._sec,
+                              "l1_dispatch"):
                         lanes.copy_to_host_async()
-                    except AttributeError:
-                        pass
-                host = np.asarray(lanes)               # d2h (pre-warmed)
+                with span("repro.hasc.l1.d2h", self._sec, "l1_d2h"):
+                    host = np.asarray(lanes)           # d2h (pre-warmed)
                 payload = host.view(np.uint8)[:nb]
             except BaseException:
                 self._free.put(buf)
                 raise
-            self._l1_read += time.perf_counter() - t0
             self._ready.put((task, buf, payload, nb,
                              crc_val if task.kind == 0 else None, i))
 
@@ -798,7 +825,7 @@ class PipelineFlight:
             applied = pin_current_thread(self.affinity)
             if self.pipeline is not None and applied is not None:
                 self.pipeline.applied_affinity = applied
-            t_l2 = 0.0
+            sec = self._sec
             sent = 0
             crcs: List[Tuple[int, int, int]] = []      # (dst, nbytes, crc)
             extents: List[Tuple[int, int]] = []        # buffer-local, sent
@@ -810,45 +837,46 @@ class PipelineFlight:
                 # the predecessor's clean-ack (its stager is done with the
                 # pipe, so the conn is ours alone from here)
                 self._wait_event(prev.done, "predecessor clean-ack")
-            t0 = time.perf_counter()
-            if delta is not None:
-                # confirmed exchange: the SMP seeds the new shard buffer
-                # by copying the base (latest clean) buffer — if the base
-                # rotated away the delta would publish garbage, so a miss
-                # aborts the flight (nothing published)
-                if not self.smp.begin(self.step, base_step=delta.base_step):
-                    raise DeltaBaseMismatch(
-                        f"delta base step {delta.base_step} is not the "
-                        f"SMP's latest clean buffer")
-            else:
-                self.smp.begin(self.step)
-            t_l3 = time.perf_counter() - t0
+            with span("repro.hasc.l3.begin", sec, "l3"):
+                if delta is not None:
+                    # confirmed exchange: the SMP seeds the new shard
+                    # buffer by copying the base (latest clean) buffer — if
+                    # the base rotated away the delta would publish
+                    # garbage, so a miss aborts the flight (nothing
+                    # published)
+                    if not self.smp.begin(self.step,
+                                          base_step=delta.base_step):
+                        raise DeltaBaseMismatch(
+                            f"delta base step {delta.base_step} is not the "
+                            f"SMP's latest clean buffer")
+                else:
+                    self.smp.begin(self.step)
             host_digesting = self.want_digests and self.encoder is None
             while True:
                 item = self._ready.get()
                 if item is _STOP:
                     break
                 task, buf, payload, nb, crc_val, idx = item
-                t0 = time.perf_counter()
                 if host_digesting:
                     # host digests (and the bit-identical skip) happen at
                     # this level: the pump hands raw reads over and never
                     # pays the CRC pass on the device-read path
+                    t0 = time.perf_counter()
                     crc_val = zlib.crc32(payload) & 0xFFFFFFFF
                     self._digests[idx] = crc_val
+                    sec["l2"] += time.perf_counter() - t0
                     if delta is not None and delta.digest \
                             and delta.prev.get(idx) == crc_val:
                         self._skipped += 1     # bit-identical: skip send
                         self._free.put(buf)
-                        t_l2 += time.perf_counter() - t0
                         continue
                     if task.kind != 0:
                         crc_val = None
                 try:
-                    self.smp.send_bucket(task.kind, task.dst, payload)
+                    with span("repro.hasc.l2.send", sec, "l2", bytes=nb):
+                        self.smp.send_bucket(task.kind, task.dst, payload)
                 finally:
                     self._free.put(buf)                # return the credit
-                t_l2 += time.perf_counter() - t0
                 sent += nb
                 if crc_val is not None:
                     crcs.append((task.dst, nb, crc_val))
@@ -858,41 +886,18 @@ class PipelineFlight:
                 return                                 # buffer stays unseen
             meta = {"spec": self.spec.to_json(), "step": self.step,
                     "extra": self.extra_meta}
-            t0 = time.perf_counter()
-            if self.want_digests:
-                # delta-enabled pipeline: the full-schedule digest table
-                # covers every own-data bucket (fresh for read buckets,
-                # inherited for skipped ones), so the own-region CRC and
-                # the per-stripe table are derived trainer-side even when
-                # only a handful of buckets were re-sent
-                crcs = [(t.dst, t.hi - t.lo, self._digests[i])
-                        for i, t in enumerate(self.schedule) if t.kind == 0]
-            if crcs:
-                # device encode path: per-bucket digests -> one combined
-                # own-region CRC plus the per-stripe table (one digest per
-                # local RAIM5 block; buckets never cross block boundaries,
-                # so grouping by dst // bs folds exactly); the SMP skips
-                # its zlib pass on both
-                crcs.sort()
-                crc_own = crc32_concat((c, nb) for _, nb, c in crcs)
-                lay = self.smp.layout
-                seg = lay.bs if lay.n > 1 else lay.own_bytes
-                per_block: Dict[int, List[Tuple[int, int]]] = {}
-                for dst, nb, c in crcs:
-                    per_block.setdefault(dst // seg, []).append((c, nb))
-                stripes = [crc32_concat(per_block[k])
-                           for k in sorted(per_block)]
-                self.smp.end(self.step, pickle.dumps(meta), crc_own=crc_own,
-                             crc_stripes=stripes)
-            else:
-                self.smp.end(self.step, pickle.dumps(meta), want_crc=True)
-            clean = self.smp.wait_clean()
-            t_l3 += time.perf_counter() - t0
+            with span("repro.hasc.l3.publish", sec, "l3"):
+                clean = self._publish(meta, crcs)
+            t_published = time.perf_counter()
             self.result = PipelineResult(
                 step=self.step, clean_step=clean, bytes_sent=sent,
-                l1_seconds=self._l1_read, l1_stall_seconds=self._l1_stall,
-                l2_seconds=t_l2, l3_seconds=t_l3,
-                wall_seconds=time.perf_counter() - self._t0,
+                l1_seconds=sec["l1_dispatch"] + sec["l1_d2h"],
+                l1_stall_seconds=sec["l1_stall"], l2_seconds=sec["l2"],
+                l3_seconds=sec["l3"], wall_seconds=t_published - self._t0,
+                l1_dispatch_seconds=sec["l1_dispatch"],
+                l1_d2h_seconds=sec["l1_d2h"],
+                l1_gate_seconds=sec["l1_gate"],
+                t_start=self._t0, t_published=t_published,
                 skipped_buckets=self._skipped,
                 delta_base=None if delta is None else delta.base_step,
                 digests=dict(self._digests) if self.want_digests else None,
@@ -907,6 +912,39 @@ class PipelineFlight:
             self.done.set()
             self.prev = None               # release the predecessor (and
                                            # its pinned leaves) promptly
+
+    def _publish(self, meta: dict,
+                 crcs: List[Tuple[int, int, int]]) -> int:
+        """`end` the flight and wait for the SMP's clean-ack; returns the
+        clean step."""
+        if self.want_digests:
+            # delta-enabled pipeline: the full-schedule digest table
+            # covers every own-data bucket (fresh for read buckets,
+            # inherited for skipped ones), so the own-region CRC and
+            # the per-stripe table are derived trainer-side even when
+            # only a handful of buckets were re-sent
+            crcs = [(t.dst, t.hi - t.lo, self._digests[i])
+                    for i, t in enumerate(self.schedule) if t.kind == 0]
+        if crcs:
+            # device encode path: per-bucket digests -> one combined
+            # own-region CRC plus the per-stripe table (one digest per
+            # local RAIM5 block; buckets never cross block boundaries,
+            # so grouping by dst // bs folds exactly); the SMP skips
+            # its zlib pass on both
+            crcs.sort()
+            crc_own = crc32_concat((c, nb) for _, nb, c in crcs)
+            lay = self.smp.layout
+            seg = lay.bs if lay.n > 1 else lay.own_bytes
+            per_block: Dict[int, List[Tuple[int, int]]] = {}
+            for dst, nb, c in crcs:
+                per_block.setdefault(dst // seg, []).append((c, nb))
+            stripes = [crc32_concat(per_block[k])
+                       for k in sorted(per_block)]
+            self.smp.end(self.step, pickle.dumps(meta), crc_own=crc_own,
+                         crc_stripes=stripes)
+        else:
+            self.smp.end(self.step, pickle.dumps(meta), want_crc=True)
+        return self.smp.wait_clean()
 
     def _drain_ready(self) -> None:
         while True:

@@ -17,6 +17,7 @@ bucket) as a measurable baseline for the pipeline's interference win.
 """
 from __future__ import annotations
 
+import collections
 import pickle
 import threading
 import time
@@ -28,10 +29,12 @@ import numpy as np
 
 from repro.core import raim5
 from repro.core.delta import DeltaLog, DeltaTracker
-from repro.core.pipeline import (DeltaBaseMismatch, LeafReader,
-                                 PipelineFlight, SnapshotPipeline,
-                                 leaf_budget, resolve_affinity)
+from repro.core.pipeline import (LEVELS, DeltaBaseMismatch, LeafReader,
+                                 PipelineFlight, PipelineResult,
+                                 SnapshotPipeline, leaf_budget,
+                                 resolve_affinity)
 from repro.core.smp import NodeLayout, SMPHandle
+from repro.core.spans import span
 from repro.core.treebytes import FlatSpec, leaf_arrays, make_flat_spec
 
 # Back-compat alias: the reader grew eviction budgets and moved into the
@@ -147,6 +150,11 @@ class SnapshotEngine:
                                  for t in self._pipeline.schedule) \
             if self._pipeline is not None else self.spec.total_bytes
         self._flights: List[PipelineFlight] = []
+        # results of collected flights, oldest first, for the facade to
+        # emit (`snapshot-published`); a caller that never reads them
+        # keeps the newest 64
+        self.published: "collections.deque[PipelineResult]" = \
+            collections.deque(maxlen=64)
         self._thread: Optional[threading.Thread] = None    # serial mode
         self._err: Optional[BaseException] = None
         self.degraded = False      # SMP unreachable: snapshots paused, not fatal
@@ -159,8 +167,7 @@ class SnapshotEngine:
         self.last_clean_step = -1
         self._persists: Dict[int, dict] = {}    # seq -> in-flight record
         self.stats = {"snapshots": 0, "bytes_sent": 0, "seconds": 0.0,
-                      "l1_seconds": 0.0, "l1_stall_seconds": 0.0,
-                      "l2_seconds": 0.0, "l3_seconds": 0.0,
+                      **{f"{k}_seconds": 0.0 for k in LEVELS},
                       "overlapped_flights": 0,
                       "persists": 0, "persist_inflight": 0,
                       "persist_seconds": 0.0,
@@ -215,6 +222,10 @@ class SnapshotEngine:
         (frequency self-limits to the achievable rate, Figure 4).  With
         `max_flights > 1` a new flight may launch while its predecessor
         is still draining L2/L3 (multi-flight overlap)."""
+        with span("repro.hasc.launch"):
+            return self._launch(state, step, extra_meta)
+
+    def _launch(self, state: Any, step: int, extra_meta: dict) -> bool:
         if self.degraded:
             return False
         if self._thread is not None and self._thread.is_alive():
@@ -318,10 +329,9 @@ class SnapshotEngine:
         st["snapshots"] += 1
         st["bytes_sent"] += res.bytes_sent
         st["seconds"] += res.wall_seconds
-        st["l1_seconds"] += res.l1_seconds
-        st["l1_stall_seconds"] += res.l1_stall_seconds
-        st["l2_seconds"] += res.l2_seconds
-        st["l3_seconds"] += res.l3_seconds
+        for k, v in res.levels().items():
+            st[f"{k}_seconds"] += v
+        self.published.append(res)
         if self._pipeline is not None:
             st["stager_affinity"] = self._pipeline.applied_affinity
         if self._tracker is not None:

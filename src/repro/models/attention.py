@@ -71,6 +71,7 @@ def _mix(scores, v, cfg):
     return o.reshape(B, Sq, cfg.num_heads * cfg.head_dim)
 
 
+@jax.named_scope("attention")
 def attention(p, cfg, x, *, window, positions, band=None, unroll=False):
     """Full-sequence attention (training / prefill).
 

@@ -86,6 +86,7 @@ def init_params(cfg: ModelConfig, key):
 
 
 # ===================================================================== fwd
+@jax.named_scope("mlp")
 def _ffn_apply(cfg, p, idx, h):
     """Returns (out, aux)."""
     if not cfg.d_ff:
@@ -219,21 +220,23 @@ def _lm_head_w(cfg, params):
 def forward(cfg: ModelConfig, params, batch, *, collect_cache=False,
             remat=None, unroll=False):
     """Full-sequence forward. Returns (loss, aux_dict)."""
-    x, labels, mask = embed_batch(cfg, params, batch)
+    with jax.named_scope("embed"):
+        x, labels, mask = embed_batch(cfg, params, batch)
     positions = jnp.arange(x.shape[1], dtype=jnp.int32)
     remat = cfg.remat if remat is None else remat
     h, aux, caches = _scan_blocks(cfg, params, x, positions,
                                   collect_cache=collect_cache, remat=remat,
                                   unroll=unroll)
-    h = rms_norm(h, params["final_norm"])
-    w_out = _lm_head_w(cfg, params)
-    if cfg.chunked_ce:
-        loss = chunked_cross_entropy(h, w_out, labels, cfg.chunked_ce, mask,
-                                     unroll=unroll)
-    else:
-        logits = h @ w_out
-        logits = shard(logits, P(("pod", "data"), None, "model"))
-        loss = cross_entropy(logits, labels, mask)
+    with jax.named_scope("head_loss"):
+        h = rms_norm(h, params["final_norm"])
+        w_out = _lm_head_w(cfg, params)
+        if cfg.chunked_ce:
+            loss = chunked_cross_entropy(h, w_out, labels, cfg.chunked_ce,
+                                         mask, unroll=unroll)
+        else:
+            logits = h @ w_out
+            logits = shard(logits, P(("pod", "data"), None, "model"))
+            loss = cross_entropy(logits, labels, mask)
     loss = loss + 0.01 * aux
     out = {"loss": loss, "aux": aux}
     if collect_cache:

@@ -85,8 +85,9 @@ def make_train_step(cfg: ModelConfig, opt: AdamConfig | None = None,
     def train_step(state: dict, batch: dict) -> tuple:
         loss, grads = (full_grads if microbatches == 1 else accum_grads)(
             state["params"], batch)
-        new_params, new_opt, gnorm = adam_update(
-            opt, grads, state["opt_state"], state["params"])
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, gnorm = adam_update(
+                opt, grads, state["opt_state"], state["params"])
         new_state = {
             "params": new_params,
             "opt_state": new_opt,
