@@ -60,12 +60,18 @@ class ReftGroup:
                  wait: bool = True) -> bool:
         """All members snapshot iteration `step` in parallel (async).
 
-        The list comprehension is deliberate: a short-circuiting all(gen)
-        would stop asking members after the first refusal, leaving the SG
-        with a partially-initiated snapshot round."""
-        started = all([e.snapshot_async(state, step, extra_meta)
-                       for e in self.engines
-                       if self.states[e.node] == NodeState.HEALTHY])
+        A round starts only when every healthy, undegraded member can
+        launch, so the members never drift onto different steps: the SG
+        stays restorable at the newest round, and a round pins one state
+        on the device, not one per member.  The list comprehension is
+        deliberate: a short-circuiting all(gen) would stop asking members
+        after the first refusal, leaving the SG with a partially-initiated
+        snapshot round."""
+        members = [e for e in self.engines
+                   if self.states[e.node] == NodeState.HEALTHY]
+        started = all([e.ready() for e in members if not e.degraded]) \
+            and all([e.snapshot_async(state, step, extra_meta)
+                     for e in members])
         if wait:
             self.wait()
         return started

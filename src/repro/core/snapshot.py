@@ -225,7 +225,9 @@ class SnapshotEngine:
         with span("repro.hasc.launch"):
             return self._launch(state, step, extra_meta)
 
-    def _launch(self, state: Any, step: int, extra_meta: dict) -> bool:
+    def ready(self) -> bool:
+        """Whether a snapshot asked for now would launch; collects the
+        flights that have finished first."""
         if self.degraded:
             return False
         if self._thread is not None and self._thread.is_alive():
@@ -234,7 +236,10 @@ class SnapshotEngine:
         self._raise_pending()
         if self.degraded:                  # the drain just found a dead SMP
             return False
-        if len(self._flights) >= self._max_flights:
+        return len(self._flights) < self._max_flights
+
+    def _launch(self, state: Any, step: int, extra_meta: dict) -> bool:
+        if not self.ready():
             return False
         leaves = leaf_arrays(state)                    # pin the references
         if self._pipeline is not None:

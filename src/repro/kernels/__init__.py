@@ -6,10 +6,13 @@
                   before the d2h copy; the REFT-Sn device encode path)
 * ssd_scan      — Mamba2 chunked state-space-duality scan
 * swa_attention — banded (sliding-window) flash attention
+* causal_attention — causal full-sequence attention of the train step on
+                  a TPU: JAX's splash kernel, built once per shape
 
 Each kernel ships <name>.py (pl.pallas_call + BlockSpec), a pure-jnp
 oracle in ref.py, swept in tests/, and — except `stage`, whose entry point
-is `stage.encode_bucket` — a jit'd wrapper in ops.py.
+is `stage.encode_bucket`, and `causal_attention`, a wrapper of splash
+whose oracle is `models/flash.py` — a jit'd wrapper in ops.py.
 """
 from repro.kernels.ops import (
     ssd_scan, swa_attention, xor_parity_decode, xor_parity_encode,

@@ -9,6 +9,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.causal_attention import block_sizes, causal_attention
 from repro.models.flash import flash_attention
 from repro.models.layers import (
     FULL_WINDOW, apply_rope, dense_init, init_rms, pdtype_of, rms_norm,
@@ -16,8 +17,10 @@ from repro.models.layers import (
 )
 
 NEG_INF = -1e30
-# Above this sequence length the online-softmax path is used so the
-# (S, S) score matrix is never materialized.
+# From this sequence length on the (S, S) score matrix is never
+# materialized: on a TPU causal, unwindowed attention lowers to the Pallas
+# kernel (`kernels/causal_attention.py`), everything else to the pure-JAX
+# online-softmax loops (`models/flash.py`).
 FLASH_THRESHOLD = 2048
 
 
@@ -89,9 +92,21 @@ def attention(p, cfg, x, *, window, positions, band=None, unroll=False):
         # unrolled dry-run compile tractable without changing totals.
         bq = max(512, S // 16)
         bk = max(1024, S // 16)
-        o = flash_attention(qg, k, v, window=window, causal=cfg.causal,
-                            band=band, unroll=unroll, block_q=bq,
-                            block_k=bk)
+
+        def loops(qg, k, v):
+            return flash_attention(qg, k, v, window=window,
+                                   causal=cfg.causal, band=band,
+                                   unroll=unroll, block_q=bq, block_k=bk)
+
+        if (band is None and not unroll and cfg.causal
+                and cfg.sliding_window is None
+                and block_sizes(S) is not None):
+            # Chosen per lowering platform, so a compile for a described
+            # TPU gets the kernel and a CPU run the loops.
+            o = jax.lax.platform_dependent(qg, k, v, tpu=causal_attention,
+                                           default=loops)
+        else:
+            o = loops(qg, k, v)
         out = o.reshape(B, S, H * hd) @ p["wo"]
         return out, (k, v)
     qpos = positions[:, None]

@@ -1,14 +1,17 @@
 """Flash-style (online-softmax) attention in pure JAX.
 
-Used automatically for long sequences so prefill/train never materializes
-the (Sq, Sk) score matrix — live memory per step is one (bq, bk) tile.
+Long-sequence attention (`models.attention.attention`) wherever the TPU
+kernel `kernels/causal_attention.py` does not run: on the CPU, for
+sliding-window and banded layers, for bidirectional attention and in
+unrolled dry runs.  It never materializes the (Sq, Sk) score matrix.
 Supports causal masking, sliding windows (traced width), GQA, and an
 optional *banded* mode (static window) that skips out-of-window KV blocks
 entirely, turning O(S^2) FLOPs into O(S*W) — the §Perf hillclimb for SWA
 architectures.
 
-Also the reference semantics for the `swa_attention` Pallas kernel (whose
-oracle is kernels/ref.py's naive masked softmax).
+Also the reference semantics for the Pallas kernels: the causal splash
+kernel's test oracle, and `swa_attention`'s (whose own oracle is
+kernels/ref.py's naive masked softmax).
 """
 from __future__ import annotations
 

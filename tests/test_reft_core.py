@@ -54,6 +54,20 @@ def test_snapshot_and_inmemory_restore(group):
     assert trees_equal(rec, st2)
 
 
+def test_snapshot_round_is_every_member_or_none(group, monkeypatch):
+    """One member that cannot launch holds the whole round back, so the
+    members never drift onto different steps (and a round pins one
+    state, not one per member)."""
+    g, state = group
+    g.snapshot(state, 1)
+    monkeypatch.setattr(g.engines[2], "ready", lambda: False)
+    assert not g.snapshot(state, 2, wait=False)
+    assert not any(e.in_flight() for e in g.engines)
+    monkeypatch.undo()
+    assert g.snapshot(state, 3)
+    assert {e.last_clean_step for e in g.engines} == {3}
+
+
 def test_raim5_tier_single_node_loss(group):
     g, state = group
     g.snapshot(state, 1)

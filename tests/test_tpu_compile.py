@@ -1,4 +1,5 @@
-"""The saving path's device programs compile for a TPU v5e.
+"""The saving path's device programs and the train step's attention
+compile for a TPU v5e.
 
 Compiled here for a described (not attached) v5e chip: what Mosaic or
 XLA:TPU would refuse on the chip fails here, at no chip time.  Nothing
@@ -99,3 +100,70 @@ def test_leaf_gather_transient_within_twice_the_bucket(one_chip, shape,
         .memory_analysis()
     leaf_bytes = int(np.prod(shape)) * jnp.dtype(dtype).itemsize
     assert mem.temp_size_in_bytes <= 2 * min(BUCKET, leaf_bytes)
+
+
+# ------------------------------------------------ train-step attention
+def _attention_grad(cfg, S, unroll=False, band=None):
+    """d(sum of one attention layer's output)/d(params, x) under
+    jax.checkpoint, as the train step's remat scan body takes it."""
+    from repro.models.attention import attention
+    from repro.models.layers import FULL_WINDOW
+    window = jnp.int32(cfg.sliding_window or FULL_WINDOW)
+    positions = jnp.arange(S, dtype=jnp.int32)
+
+    def loss(p, x):
+        o, _ = attention(p, cfg, x, window=window, positions=positions,
+                         band=band, unroll=unroll)
+        return jnp.sum(o.astype(jnp.float32))
+    return jax.grad(jax.checkpoint(loss), argnums=(0, 1))
+
+
+def _attention_args(cfg, B, S, sharding=None):
+    from repro.models.attention import init_attn
+    p = jax.eval_shape(lambda: init_attn(jax.random.PRNGKey(0), cfg))
+    p = jax.tree.map(lambda t: _spec(t.shape, t.dtype, sharding), p)
+    return p, _spec((B, S, cfg.d_model), jnp.bfloat16, sharding)
+
+
+def test_causal_attention_lowers_to_kernel_for_v5e(one_chip):
+    """opt-350m's layer (B 4, S 2048, H 16, hd 64): the splash kernel,
+    forward and backward, no block loop, and temp far under the loops'
+    (4.86 GB for the scanned form)."""
+    from repro.configs import get_config
+    cfg = get_config("opt-350m")
+    compiled = jax.jit(_attention_grad(cfg, 2048)) \
+        .lower(*_attention_args(cfg, 4, 2048, one_chip)).compile()
+    text = compiled.as_text()
+    assert _is_kernel(compiled)
+    assert "while" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("case", ["sliding_window", "banded", "unroll",
+                                  "non_causal"])
+def test_other_attention_keeps_the_loops_for_v5e(one_chip, case):
+    """Windowed, banded, unrolled (dry-run) and bidirectional attention
+    lower to the pure-JAX loops on a TPU too."""
+    import dataclasses
+    from repro.configs import get_config
+    cfg = get_config("opt-350m")
+    band, unroll = None, False
+    if case in ("sliding_window", "banded"):
+        cfg = dataclasses.replace(cfg, sliding_window=512)
+        band = 512 if case == "banded" else None
+    elif case == "unroll":
+        unroll = True
+    else:
+        cfg = dataclasses.replace(cfg, causal=False)
+    lowered = jax.jit(_attention_grad(cfg, 2048, unroll=unroll, band=band)) \
+        .lower(*_attention_args(cfg, 1, 2048, one_chip))
+    assert "tpu_custom_call" not in lowered.as_text()
+
+
+def test_causal_attention_lowers_to_the_loops_on_cpu():
+    """The same call lowered for the CPU has no custom call at all."""
+    from repro.configs import get_config
+    cfg = get_config("opt-350m")
+    text = jax.jit(_attention_grad(cfg, 2048)) \
+        .lower(*_attention_args(cfg, 4, 2048)).as_text()
+    assert "custom_call" not in text
