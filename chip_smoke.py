@@ -287,8 +287,8 @@ def sharded_phase(*, ckpt_dir: str, arch: str = "dbrx-132b",
         jax.random.normal(jax.random.PRNGKey(1), (batch, seq, cfg.d_model)),
         NamedSharding(mesh, P("data", None, None)))
     with use_mesh(mesh), jax.default_matmul_precision("highest"):
-        y_ep, _ = jax.jit(lambda p, x: moe_ffn_ep(p, c32, x))(p, x)
-        y_ref, _ = jax.jit(lambda p, x: moe_ffn_gspmd(p, c32, x))(p, x)
+        y_ep, _, _ = jax.jit(lambda p, x: moe_ffn_ep(p, c32, x))(p, x)
+        y_ref, _, _ = jax.jit(lambda p, x: moe_ffn_gspmd(p, c32, x))(p, x)
     rec["ep_max_abs_diff"] = float(np.max(np.abs(
         np.asarray(y_ep) - np.asarray(y_ref))))
     rec["gspmd_max_abs"] = float(np.max(np.abs(np.asarray(y_ref))))

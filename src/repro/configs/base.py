@@ -31,6 +31,11 @@ class ModelConfig:
     # --- attention flavour ---
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    # YaRN (arXiv:2309.00071) on the full-attention layers of a windowed
+    # config, extending `yarn_original_max` positions by `yarn_factor`:
+    # 0 = the default RoPE everywhere
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 0
     # sliding window width; None = full attention everywhere
     sliding_window: Optional[int] = None
     # local:global interleave -- every `global_every`-th layer is global
@@ -43,7 +48,12 @@ class ModelConfig:
     experts_per_token: int = 0
     moe_every: int = 1               # apply MoE on layers where idx % moe_every == moe_offset
     moe_offset: int = 0
+    # tokens an expert takes, over its even share (capacity dispatch, drops
+    # the overflow); 0 = dropless grouped products over the routed rows
     capacity_factor: float = 1.25
+    # experts this chip holds, from expert 0 (0 = all): its share of an
+    # expert-parallel layer, whose other shares live on other chips
+    experts_held: int = 0
 
     # --- SSM (mamba2 / hybrid) ---
     ssm_state: int = 0
@@ -109,6 +119,10 @@ class ModelConfig:
             return False
         return idx % self.moe_every == self.moe_offset
 
+    @property
+    def num_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
+
     def layer_window(self, idx: int) -> Optional[int]:
         """Effective attention window of layer `idx` (None = full)."""
         if self.sliding_window is None:
@@ -162,9 +176,8 @@ class ModelConfig:
                 n += di * D                               # out_proj
                 n += di                                   # gate norm
             if self.layer_is_moe(i):
-                E = self.num_experts
-                n += D * E                                # router
-                n += E * (3 * D * F)                      # gated experts
+                n += D * self.num_experts                 # router
+                n += self.num_experts_held * (3 * D * F)  # gated experts
             else:
                 if F:
                     n += 3 * D * F                        # gated MLP
@@ -178,7 +191,8 @@ class ModelConfig:
         D, F = self.d_model, self.d_ff
         total = self.param_count()
         n_moe = sum(1 for i in range(self.num_layers) if self.layer_is_moe(i))
-        dead = n_moe * (self.num_experts - self.experts_per_token) * (3 * D * F)
+        dead = n_moe * (self.num_experts_held - self.experts_per_token) \
+            * (3 * D * F)
         return total - dead
 
     def reduced(self) -> "ModelConfig":
@@ -196,7 +210,11 @@ class ModelConfig:
             experts_per_token=min(self.experts_per_token, 2),
             # drop-free in smoke tests (C >= T*k); the capacity drop rule is
             # unit-tested separately against the python oracle
-            capacity_factor=float(max(self.num_experts, 1)),
+            capacity_factor=(float(max(self.num_experts, 1))
+                             if self.capacity_factor else 0.0),
+            # a held share stays a share: half of the smoke experts
+            experts_held=(min(self.num_experts, 4) // 2
+                          if self.experts_held else 0),
             moe_every=min(self.moe_every, 2) if self.num_experts else 1,
             ssm_state=min(self.ssm_state, 64) if self.ssm_state else 0,
             ssm_head_dim=32 if self.ssm_state else 64,
@@ -260,7 +278,7 @@ def _load_all():
     from repro.configs import (  # noqa: F401
         starcoder2_3b, hubert_xlarge, jamba_v01_52b, phi3_vision_4p2b,
         dbrx_132b, kimi_k2_1t, qwen3_8b, mamba2_130m, deepseek_67b,
-        gemma3_4b, opt_family,
+        gemma3_4b, opt_family, mellum2_12b_a2p5b,
     )
 
 

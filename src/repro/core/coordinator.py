@@ -294,6 +294,7 @@ class ReftGroup:
                 e.close()
             except Exception:
                 pass
+        self.template = None               # a closed group pins no state
 
 
 class Reft:

@@ -26,13 +26,14 @@ dirty fraction exceeds `dirty_threshold` (delta saves nothing dense).
 from __future__ import annotations
 
 import bisect
+import math
 from dataclasses import dataclass, field
 from typing import Dict, FrozenSet, List, Optional, Sequence, Tuple
 
 Range = Tuple[int, int]
 
-# leaves whose leading dim is the expert axis (params and their optimizer
-# moments share path suffixes)
+# leaves with an expert axis (params and their optimizer moments share path
+# suffixes)
 EXPERT_LEAF_MARKERS = ("wi_gate", "wi_up", "wo", "expert")
 
 
@@ -73,28 +74,38 @@ def task_dirty(task, ranges: Sequence[Range]) -> bool:
 
 
 def expert_dirty_ranges(spec, touched: Sequence[bool],
-                        markers: Sequence[str] = EXPERT_LEAF_MARKERS
+                        markers: Sequence[str] = EXPERT_LEAF_MARKERS,
+                        held: Optional[Sequence[int]] = None
                         ) -> List[Range]:
     """Touched-expert mask -> conservative global dirty byte ranges.
 
-    Expert-stacked leaves (leading dim == len(touched), path naming an
-    expert weight) contribute only their touched experts' slices; every
-    other leaf (router, norms, embeddings, scalars — all updated every
-    step) is whole-leaf dirty."""
-    E = len(touched)
+    `touched` is over every expert of the model (the router's width);
+    `held` lists the global ids of the experts this chip holds, in the
+    order of its leaves' expert axis (default: all of them).  Expert
+    leaves (a path naming an expert weight, the expert axis leading, or
+    second under the layer stack `blocks`, of size len(held)) contribute
+    only their touched held experts' slices, in every layer of the stack;
+    every other leaf (router, norms, embeddings, scalars — all updated
+    every step) is whole-leaf dirty."""
+    held = range(len(touched)) if held is None else held
+    E = len(held)
+    mask = [bool(touched[g]) for g in held]
     out: List[Range] = []
     for leaf in spec.leaves:
-        stacked = (E > 1 and len(leaf.shape) >= 1 and leaf.shape[0] == E
-                   and leaf.nbytes % E == 0
+        axis = 1 if "blocks" in leaf.path else 0
+        stacked = (E > 1 and len(leaf.shape) > axis
+                   and leaf.shape[axis] == E
                    and any(m in leaf.path for m in markers))
         if not stacked:
             out.append((leaf.offset, leaf.offset + leaf.nbytes))
             continue
-        per = leaf.nbytes // E
-        for e in range(E):
-            if touched[e]:
-                out.append((leaf.offset + e * per,
-                            leaf.offset + (e + 1) * per))
+        lead = math.prod(leaf.shape[:axis])
+        per = leaf.nbytes // (lead * E)
+        for i in range(lead):
+            for e in range(E):
+                if mask[e]:
+                    lo = leaf.offset + (i * E + e) * per
+                    out.append((lo, lo + per))
     return merge_ranges(out)
 
 

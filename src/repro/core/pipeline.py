@@ -909,6 +909,10 @@ class PipelineFlight:
             self._abort.set()
         finally:
             self._drain_ready()            # return credits of unsent items
+            # a finished flight pins nothing: its state's device buffers
+            # go as soon as the trainer drops them (a pump still running
+            # holds its own references)
+            self.leaves = self.encoder = None
             self.done.set()
             self.prev = None               # release the predecessor (and
                                            # its pinned leaves) promptly

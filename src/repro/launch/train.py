@@ -157,7 +157,9 @@ def main(argv=None, *, observe: Optional[Callable[..., None]] = None):
             from repro.models.moe import TOUCHED
             fspec = sess.checkpointer.group.engines[0].spec
             sess.checkpointer.set_dirty_provider(
-                lambda: expert_dirty_ranges(fspec, TOUCHED.consume()))
+                lambda: expert_dirty_ranges(
+                    fspec, TOUCHED.consume(),
+                    held=range(cfg.num_experts_held)))
         if sess.restored is not None:
             res = sess.restored
             print(f"[resume] tier={res.tier} step={res.step}"

@@ -1,10 +1,14 @@
 """GQA attention with RoPE, optional qk-norm and sliding windows.
 
-The window width is a *traced per-layer value* (scanned array), so local and
-global layers share one scan body: global layers carry the FULL_WINDOW
-sentinel.  Decode attends one query against a pre-allocated KV cache.
+A layer's window is a Python value where the layer stack fixes it (None:
+full attention; an int: a sliding window), so the layer lowers to its own
+mask, kernel and RoPE kind; where it does not (a local:global pattern that
+does not tile the depth), a traced int32 with the FULL_WINDOW sentinel on
+global layers.  Decode attends one query against a pre-allocated KV cache.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -18,9 +22,9 @@ from repro.models.layers import (
 
 NEG_INF = -1e30
 # From this sequence length on the (S, S) score matrix is never
-# materialized: on a TPU causal, unwindowed attention lowers to the Pallas
-# kernel (`kernels/causal_attention.py`), everything else to the pure-JAX
-# online-softmax loops (`models/flash.py`).
+# materialized: on a TPU causal attention whose window is fixed at trace
+# time lowers to the Pallas kernel (`kernels/causal_attention.py`),
+# everything else to the pure-JAX online-softmax loops (`models/flash.py`).
 FLASH_THRESHOLD = 2048
 
 
@@ -40,7 +44,26 @@ def init_attn(key, cfg):
     return p
 
 
-def _project_qkv(p, cfg, x, positions):
+def _rope(cfg, positions, window):
+    """A layer's cos/sin: YaRN on the full layers of a config that gives
+    it, the default RoPE everywhere else."""
+    if not cfg.yarn_factor:
+        return rope_angles(positions, cfg.head_dim, cfg.rope_theta)
+    if window is not None and not isinstance(window, int):
+        raise ValueError(f"{cfg.name}: the RoPE kind needs a static window")
+    if window is not None:
+        return rope_angles(positions, cfg.head_dim, cfg.rope_theta)
+    return rope_angles(positions, cfg.head_dim, cfg.rope_theta,
+                       yarn_factor=cfg.yarn_factor,
+                       original_max=cfg.yarn_original_max)
+
+
+def _width(window):
+    """The window as a number for masks (FULL_WINDOW for None)."""
+    return FULL_WINDOW if window is None else window
+
+
+def _project_qkv(p, cfg, x, positions, window):
     B, S, _ = x.shape
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = (x @ p["wq"]).reshape(B, S, H, hd)
@@ -49,7 +72,7 @@ def _project_qkv(p, cfg, x, positions):
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"])
         k = rms_norm(k, p["k_norm"])
-    cos, sin = rope_angles(positions, hd, cfg.rope_theta)
+    cos, sin = _rope(cfg, positions, window)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     return q, k, v
@@ -78,12 +101,13 @@ def _mix(scores, v, cfg):
 def attention(p, cfg, x, *, window, positions, band=None, unroll=False):
     """Full-sequence attention (training / prefill).
 
-    window: traced int32 scalar (FULL_WINDOW for global layers).
+    window: None (full), a static int, or a traced int32 scalar
+    (FULL_WINDOW for global layers).
     positions: (S,) int32 (assumed contiguous from 0 for the flash path).
     band: static int window for exact banded attention (§Perf hillclimb).
     Returns (out, (k, v)) so prefill can populate the cache.
     """
-    q, k, v = _project_qkv(p, cfg, x, positions)
+    q, k, v = _project_qkv(p, cfg, x, positions, window)
     B, S = x.shape[0], x.shape[1]
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     if S >= FLASH_THRESHOLD or band is not None:
@@ -94,16 +118,17 @@ def attention(p, cfg, x, *, window, positions, band=None, unroll=False):
         bk = max(1024, S // 16)
 
         def loops(qg, k, v):
-            return flash_attention(qg, k, v, window=window,
+            return flash_attention(qg, k, v, window=_width(window),
                                    causal=cfg.causal, band=band,
                                    unroll=unroll, block_q=bq, block_k=bk)
 
         if (band is None and not unroll and cfg.causal
-                and cfg.sliding_window is None
+                and (window is None or isinstance(window, int))
                 and block_sizes(S) is not None):
             # Chosen per lowering platform, so a compile for a described
             # TPU gets the kernel and a CPU run the loops.
-            o = jax.lax.platform_dependent(qg, k, v, tpu=causal_attention,
+            kernel = functools.partial(causal_attention, window=window)
+            o = jax.lax.platform_dependent(qg, k, v, tpu=kernel,
                                            default=loops)
         else:
             o = loops(qg, k, v)
@@ -112,7 +137,8 @@ def attention(p, cfg, x, *, window, positions, band=None, unroll=False):
     qpos = positions[:, None]
     kpos = positions[None, :]
     ok = kpos - qpos < 1 if cfg.causal else jnp.ones((S, S), bool)
-    ok = ok & (qpos - kpos < window) & (kpos - qpos < window)
+    w = _width(window)
+    ok = ok & (qpos - kpos < w) & (kpos - qpos < w)
     scores = _gqa_scores(q, k, cfg)
     scores = jnp.where(ok[None, None, None], scores, NEG_INF)
     out = _mix(scores, v, cfg) @ p["wo"]
@@ -126,7 +152,7 @@ def attention_decode(p, cfg, x, cache_k, cache_v, *, window, index):
     the sliding window. Returns (out, new_k, new_v).
     """
     pos = jnp.full((1,), index, jnp.int32)
-    q, k1, v1 = _project_qkv(p, cfg, x, pos)
+    q, k1, v1 = _project_qkv(p, cfg, x, pos, window)
     Smax = cache_k.shape[1]
     # Ring-buffer write: slot = index % Smax. When Smax covers the full
     # sequence this is a plain positional write; when the cache is
@@ -138,7 +164,7 @@ def attention_decode(p, cfg, x, cache_k, cache_v, *, window, index):
                                              slot, axis=1)
     j = jnp.arange(Smax, dtype=jnp.int32)
     kpos = index - jax.lax.rem(index - j, Smax)           # true position of slot j
-    ok = (kpos >= 0) & (kpos <= index) & (index - kpos < window)
+    ok = (kpos >= 0) & (kpos <= index) & (index - kpos < _width(window))
     scores = _gqa_scores(q, ck, cfg)                   # (B,KV,G,1,Smax)
     scores = jnp.where(ok[None, None, None, None], scores, NEG_INF)
     out = _mix(scores, cv, cfg) @ p["wo"]

@@ -1,6 +1,8 @@
 """Shared neural-net primitives (pure JAX, pytree params)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -36,12 +38,37 @@ def dense_init(key, shape, dtype, scale=None):
 
 
 # ---------------------------------------------------------------- RoPE
-def rope_angles(positions, head_dim, theta):
-    """positions: (...,) int -> cos/sin of shape (..., head_dim//2)."""
+# YaRN's ramp ends: the paper's alpha and beta (rotations over the
+# original length), which the configs that use YaRN all keep
+YARN_BETA_FAST, YARN_BETA_SLOW = 32.0, 1.0
+
+def rope_angles(positions, head_dim, theta, *, yarn_factor=0.0,
+                original_max=0):
+    """positions: (...,) int -> cos/sin of shape (..., head_dim//2).
+
+    yarn_factor s > 0: YaRN (arXiv:2309.00071) -- each frequency blends its
+    extrapolated value theta^(-2i/d) with the interpolated one (divided by
+    s) over a linear ramp between the dimensions that turn YARN_BETA_FAST
+    and YARN_BETA_SLOW times over `original_max` positions; cos and sin
+    are scaled by the attention factor 0.1 ln s + 1."""
     half = head_dim // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if not yarn_factor:
+        ang = positions.astype(jnp.float32)[..., None] * freqs
+        return jnp.cos(ang), jnp.sin(ang)
+
+    def dim_of(turns):
+        return (head_dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(dim_of(YARN_BETA_FAST)), 0)
+    hi = min(math.ceil(dim_of(YARN_BETA_SLOW)), head_dim - 1)
+    hi = hi if hi > lo else lo + 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - lo) / (hi - lo),
+                    0.0, 1.0)
+    freqs = freqs / yarn_factor * ramp + freqs * (1.0 - ramp)
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+    scale = 0.1 * math.log(yarn_factor) + 1.0
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
 
 
 def apply_rope(x, cos, sin):

@@ -2,13 +2,15 @@
 
 Layer stacks are *scanned* (stacked params, `lax.scan`) so the HLO stays
 compact for 95-layer / trillion-parameter configs.  Heterogeneous hybrids
-(Jamba) scan over *periods* whose body unrolls the static per-position layer
-kinds.  Local-vs-global attention is data, not structure: the per-layer
-window width is a scanned int32 (FULL_WINDOW sentinel for global layers).
+(Jamba) and local:global attention patterns (Mellum2, Gemma3) scan over
+*periods* whose body unrolls the static per-position layer kinds, so each
+position's window and RoPE kind are Python values.  Only a pattern that
+does not tile the depth scans one layer at a time with the window as a
+scanned int32 (FULL_WINDOW sentinel for global layers).
 """
 from __future__ import annotations
 
-import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -55,11 +57,26 @@ def _stack_period(cfg: ModelConfig):
         period = cfg.attn_period
         if cfg.num_experts:
             # the scan body must see a pattern that repeats exactly
-            import math
             period = math.lcm(period, cfg.moe_every)
         assert cfg.num_layers % period == 0, (cfg.name, period)
         return period, cfg.num_layers // period
+    if cfg.sliding_window is not None and cfg.global_every:
+        period = cfg.global_every
+        if cfg.num_experts:
+            period = math.lcm(period, cfg.moe_every)
+        if cfg.num_layers % period == 0:
+            return period, cfg.num_layers // period
     return 1, cfg.num_layers
+
+
+def static_windows(cfg: ModelConfig):
+    """Each period position's window as a Python value (None: full), or
+    None where the period does not fix it (the scanned int32 then)."""
+    period, _ = _stack_period(cfg)
+    if (cfg.sliding_window is None or not cfg.global_every
+            or period % cfg.global_every == 0):
+        return [cfg.layer_window(i) for i in range(period)]
+    return None
 
 
 def init_params(cfg: ModelConfig, key):
@@ -86,33 +103,51 @@ def init_params(cfg: ModelConfig, key):
 
 
 # ===================================================================== fwd
+def _acc0(cfg):
+    """What the layer stack sums (and maxes) alongside h."""
+    acc = {"aux": jnp.zeros((), jnp.float32)}
+    if cfg.num_experts:
+        acc.update(moe_rows_here=jnp.zeros((), jnp.int32),
+                   moe_max_load=jnp.zeros((), jnp.float32))
+    return acc
+
+
+def merge_stats(acc, new):
+    """Fold one layer's (or microbatch's) counters into `acc`: sums, but
+    the max of `moe_max_load`."""
+    return {k: (jnp.maximum(v, new[k]) if k == "moe_max_load" else
+                v + new[k]) if k in new else v
+            for k, v in acc.items()}
+
+
 @jax.named_scope("mlp")
 def _ffn_apply(cfg, p, idx, h):
-    """Returns (out, aux)."""
+    """Returns (out, counters): `aux`, and the MoE load on MoE layers."""
     if not cfg.d_ff:
-        return jnp.zeros_like(h), jnp.zeros((), jnp.float32)
+        return jnp.zeros_like(h), {"aux": jnp.zeros((), jnp.float32)}
     h_in = rms_norm(h, p["ln2"])
     if cfg.layer_is_moe(idx):
-        out, aux = moe_ffn(p["ffn"], cfg, h_in)
-        return out, aux
-    return mlp(p["ffn"], h_in), jnp.zeros((), jnp.float32)
+        out, aux, stats = moe_ffn(p["ffn"], cfg, h_in)
+        return out, {"aux": aux, **stats}
+    return mlp(p["ffn"], h_in), {"aux": jnp.zeros((), jnp.float32)}
 
 
 def _layer_full(cfg, p, idx, h, w, positions, collect_cache,
                 static_idx=None, unroll=False):
-    """One layer on the full sequence. Returns (h, aux, cache_entry).
+    """One layer on the full sequence. Returns (h, counters, cache_entry).
 
+    w: the layer's window (None, an int, or traced; `attention`).
     static_idx: the *global* layer index when it is statically known
     (unrolled dry-run) — enables exact banded attention per layer.
     """
     h = shard(h, P(("pod", "data"), None, None))
     if cfg.layer_kind(idx) == ATTN:
         band = None
-        if cfg.banded_attention and cfg.sliding_window is not None:
+        if cfg.banded_attention:
             if static_idx is not None:
                 band = cfg.layer_window(static_idx)   # None on global layers
-            elif not cfg.global_every:
-                band = cfg.sliding_window             # homogeneous SWA
+            elif isinstance(w, int):
+                band = w
         a, (k, v) = attention(p["mix"], cfg, rms_norm(h, p["ln1"]),
                               window=w, positions=positions, band=band,
                               unroll=unroll)
@@ -124,9 +159,9 @@ def _layer_full(cfg, p, idx, h, w, positions, collect_cache,
                                              chunk=cfg.ssd_chunk)
         entry = ({"conv": conv_state, "h": h_final} if collect_cache else {})
     h = h + a
-    f, aux = _ffn_apply(cfg, p, idx, h)
+    f, stats = _ffn_apply(cfg, p, idx, h)
     h = h + f
-    return h, aux, entry
+    return h, stats, entry
 
 
 def _layer_decode(cfg, p, idx, h, w, index, entry):
@@ -145,33 +180,50 @@ def _layer_decode(cfg, p, idx, h, w, index, entry):
     return h + f, new_entry
 
 
+def _windows(cfg):
+    """(scanned windows (n_periods, period) or None, static per-position
+    windows or None): exactly one of the two is given."""
+    period, n_periods = _stack_period(cfg)
+    static = static_windows(cfg)
+    if static is not None:
+        return None, static
+    return window_array(cfg).reshape(n_periods, period), None
+
+
 def _scan_blocks(cfg, params, h, positions, *, collect_cache=False,
                  remat=False, unroll=False):
     period, n_periods = _stack_period(cfg)
-    win = window_array(cfg).reshape(n_periods, period)
+    win, static = _windows(cfg)
+    policy = (jax.checkpoint_policies.dots_saveable
+              if cfg.remat_policy == "dots" else None)
+
+    def position(i, sidx):
+        def layer(p, h, w_scanned):
+            w = static[i] if static is not None else w_scanned
+            return _layer_full(cfg, p, i, h, w, positions, collect_cache,
+                               static_idx=sidx, unroll=unroll)
+        # remat per layer: one layer's activations live at a time,
+        # whatever the period
+        return jax.checkpoint(layer, policy=policy) if remat else layer
 
     def make_body(period_idx=None):
         def body(carry, xs):
-            h, aux = carry
+            h, acc = carry
             p_period, w_period = xs
             entries = {}
             for i in range(period):
                 sidx = (None if period_idx is None
                         else period_idx * period + i)
-                h, a, e = _layer_full(cfg, p_period[f"pos{i}"], i, h,
-                                      w_period[i], positions, collect_cache,
-                                      static_idx=sidx, unroll=unroll)
-                aux = aux + a
+                h, st, e = position(i, sidx)(
+                    p_period[f"pos{i}"], h,
+                    None if w_period is None else w_period[i])
+                acc = merge_stats(acc, st)
                 if collect_cache:
                     entries[f"pos{i}"] = e
-            return (h, aux), entries
-        if remat:
-            policy = (jax.checkpoint_policies.dots_saveable
-                      if cfg.remat_policy == "dots" else None)
-            return jax.checkpoint(body, policy=policy)
+            return (h, acc), entries
         return body
 
-    carry0 = (h, jnp.zeros((), jnp.float32))
+    carry0 = (h, _acc0(cfg))
     if unroll:
         # Dry-run mode: XLA's cost analysis counts a while-loop body once,
         # so roofline FLOPs are extracted from the unrolled program.  The
@@ -179,16 +231,17 @@ def _scan_blocks(cfg, params, h, positions, *, collect_cache=False,
         carry = carry0
         entries_list = []
         for i in range(n_periods):
-            xs_i = (jax.tree.map(lambda a: a[i], params["blocks"]), win[i])
+            xs_i = (jax.tree.map(lambda a: a[i], params["blocks"]),
+                    None if win is None else win[i])
             carry, entries = make_body(i)(carry, xs_i)
             entries_list.append(entries)
-        h, aux = carry
+        h, acc = carry
         caches = (jax.tree.map(lambda *xs: jnp.stack(xs), *entries_list)
                   if collect_cache else {})
-        return h, aux, caches
-    (h, aux), caches = jax.lax.scan(make_body(), carry0,
+        return h, acc, caches
+    (h, acc), caches = jax.lax.scan(make_body(), carry0,
                                     (params["blocks"], win))
-    return h, aux, caches
+    return h, acc, caches
 
 
 def embed_batch(cfg: ModelConfig, params, batch):
@@ -224,7 +277,7 @@ def forward(cfg: ModelConfig, params, batch, *, collect_cache=False,
         x, labels, mask = embed_batch(cfg, params, batch)
     positions = jnp.arange(x.shape[1], dtype=jnp.int32)
     remat = cfg.remat if remat is None else remat
-    h, aux, caches = _scan_blocks(cfg, params, x, positions,
+    h, acc, caches = _scan_blocks(cfg, params, x, positions,
                                   collect_cache=collect_cache, remat=remat,
                                   unroll=unroll)
     with jax.named_scope("head_loss"):
@@ -237,8 +290,8 @@ def forward(cfg: ModelConfig, params, batch, *, collect_cache=False,
             logits = h @ w_out
             logits = shard(logits, P(("pod", "data"), None, "model"))
             loss = cross_entropy(logits, labels, mask)
-    loss = loss + 0.01 * aux
-    out = {"loss": loss, "aux": aux}
+    loss = loss + 0.01 * acc["aux"]
+    out = {"loss": loss, **acc}
     if collect_cache:
         out["cache"] = caches
     return loss, out
@@ -286,14 +339,15 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *, unroll=False):
     period, n_periods = _stack_period(cfg)
     index = cache["index"]
     x = params["embed"][tokens].astype(dtype_of(cfg))
-    win = window_array(cfg).reshape(n_periods, period)
+    win, static = _windows(cfg)
 
     def body(h, xs):
         p_period, w_period, entries = xs
         new_entries = {}
         for i in range(period):
-            h, ne = _layer_decode(cfg, p_period[f"pos{i}"], i, h,
-                                  w_period[i], index, entries[f"pos{i}"])
+            w = static[i] if static is not None else w_period[i]
+            h, ne = _layer_decode(cfg, p_period[f"pos{i}"], i, h, w, index,
+                                  entries[f"pos{i}"])
             new_entries[f"pos{i}"] = ne
         return h, new_entries
 
@@ -301,7 +355,8 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *, unroll=False):
         h = x
         ne_list = []
         for i in range(n_periods):
-            xs_i = (jax.tree.map(lambda a: a[i], params["blocks"]), win[i],
+            xs_i = (jax.tree.map(lambda a: a[i], params["blocks"]),
+                    None if win is None else win[i],
                     jax.tree.map(lambda a: a[i], cache["entries"]))
             h, ne = body(h, xs_i)
             ne_list.append(ne)

@@ -57,11 +57,15 @@ def make_train_step(cfg: ModelConfig, opt: AdamConfig | None = None,
     opt = opt if opt is not None else AdamConfig()
 
     def loss_fn(p, batch):
-        loss, _ = M.forward(cfg, p, batch, unroll=unroll)
-        return loss
+        loss, out = M.forward(cfg, p, batch, unroll=unroll)
+        # the step's counters: the MoE load, where the config has experts
+        return loss, {k: jax.lax.stop_gradient(v) for k, v in out.items()
+                      if k.startswith("moe_")}
 
     def full_grads(params, batch):
-        return jax.value_and_grad(loss_fn)(params, batch)
+        (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params, batch)
+        return loss, grads, stats
 
     def accum_grads(params, batch):
         def split(x):
@@ -71,20 +75,24 @@ def make_train_step(cfg: ModelConfig, opt: AdamConfig | None = None,
         mb = jax.tree.map(split, batch)
 
         def body(carry, b_i):
-            loss_acc, g_acc = carry
-            loss_i, g_i = jax.value_and_grad(loss_fn)(params, b_i)
+            loss_acc, g_acc, st_acc = carry
+            (loss_i, st_i), g_i = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, b_i)
             g_acc = jax.tree.map(jnp.add, g_acc, g_i)
-            return (loss_acc + loss_i, g_acc), None
+            return (loss_acc + loss_i, g_acc,
+                    M.merge_stats(st_acc, st_i)), None
 
         zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                              params)
-        (loss, grads), _ = jax.lax.scan(body, (jnp.zeros(()), zeros), mb)
+        st0 = {k: v for k, v in M._acc0(cfg).items() if k.startswith("moe_")}
+        (loss, grads, stats), _ = jax.lax.scan(
+            body, (jnp.zeros(()), zeros, st0), mb)
         inv = 1.0 / microbatches
-        return loss * inv, jax.tree.map(lambda g: g * inv, grads)
+        return loss * inv, jax.tree.map(lambda g: g * inv, grads), stats
 
     def train_step(state: dict, batch: dict) -> tuple:
-        loss, grads = (full_grads if microbatches == 1 else accum_grads)(
-            state["params"], batch)
+        loss, grads, stats = (full_grads if microbatches == 1
+                              else accum_grads)(state["params"], batch)
         with jax.named_scope("optimizer"):
             new_params, new_opt, gnorm = adam_update(
                 opt, grads, state["opt_state"], state["params"])
@@ -94,7 +102,7 @@ def make_train_step(cfg: ModelConfig, opt: AdamConfig | None = None,
             "step": state["step"] + 1,
             "rng": jax.random.fold_in(state["rng"], state["step"]),
         }
-        return new_state, {"loss": loss, "grad_norm": gnorm}
+        return new_state, {"loss": loss, "grad_norm": gnorm, **stats}
 
     return train_step
 

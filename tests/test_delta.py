@@ -92,6 +92,32 @@ def test_expert_dirty_ranges_stacked_vs_dense():
     assert allr == [(0, spec.total_bytes)]
 
 
+def test_expert_dirty_ranges_held_share_on_the_stacked_axis():
+    """A chip holding experts 4..7 of 8, under the layer stack (expert axis
+    1 of (periods, E_held, ...)): the global touched mask picks the held
+    experts' slices in every period; experts held elsewhere dirty
+    nothing here."""
+    L, E, Eh = 2, 8, 4
+    spec = make_flat_spec({"blocks": {"pos0": {"ffn": {
+        "router": jnp.zeros((L, 3, E), jnp.float32),
+        "wi_gate": jnp.zeros((L, Eh, 2, 2), jnp.float32)}}}})
+    gate = next(l for l in spec.leaves if "wi_gate" in l.path)
+    router = next(l for l in spec.leaves if "router" in l.path)
+    per = gate.nbytes // (L * Eh)
+    touched = [True, True, False, False, False, True, False, True]
+    got = expert_dirty_ranges(spec, touched, held=range(4, 8))
+    want = merge_ranges(
+        [(router.offset, router.offset + router.nbytes)]
+        + [(gate.offset + (i * Eh + e) * per,
+            gate.offset + (i * Eh + e + 1) * per)
+           for i in range(L) for e in (1, 3)])      # global ids 5 and 7
+    assert got == want
+    # touched only elsewhere: the expert leaf stays clean
+    assert expert_dirty_ranges(spec, [True] * 4 + [False] * 4,
+                               held=range(4, 8)) == \
+        [(router.offset, router.offset + router.nbytes)]
+
+
 def test_delta_tracker_policy():
     sched = [SimpleNamespace(kind=0, lo=0, hi=10, sources=None),
              SimpleNamespace(kind=0, lo=10, hi=100, sources=None)]

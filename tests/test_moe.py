@@ -59,7 +59,7 @@ def test_moe_matches_oracle(cf):
     cfg = dataclasses.replace(base, capacity_factor=cf)
     p = init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 6, cfg.d_model))
-    y, aux = moe_ffn(p, cfg, x)
+    y, aux, _ = moe_ffn(p, cfg, x)
     ref = oracle(p, cfg, x)
     np.testing.assert_allclose(np.asarray(y, np.float32), ref,
                                atol=2e-4, rtol=1e-3)
@@ -72,7 +72,7 @@ def test_moe_top1_and_many_experts():
                               capacity_factor=8.0)
     p = init_moe(jax.random.PRNGKey(3), cfg)
     x = jax.random.normal(jax.random.PRNGKey(4), (1, 8, cfg.d_model))
-    y, _ = moe_ffn(p, cfg, x)
+    y, _, _ = moe_ffn(p, cfg, x)
     ref = oracle(p, cfg, x)
     np.testing.assert_allclose(np.asarray(y, np.float32), ref,
                                atol=2e-4, rtol=1e-3)
@@ -84,7 +84,7 @@ def test_moe_grads_flow_through_router():
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 4, cfg.d_model))
 
     def loss(p):
-        y, aux = moe_ffn(p, cfg, x)
+        y, aux, _ = moe_ffn(p, cfg, x)
         return jnp.sum(y ** 2) + 0.01 * aux
 
     g = jax.grad(loss)(p)

@@ -22,11 +22,11 @@ SCRIPT = textwrap.dedent("""
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model))
     mesh = jax.make_mesh((2, 4), ("data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    y_ref, _ = moe_ffn_gspmd(p, cfg, x)
+    y_ref, _, _ = moe_ffn_gspmd(p, cfg, x)
     with use_mesh(mesh):
-        y_ep, _ = jax.jit(lambda p, x: moe_ffn_ep(p, cfg, x))(p, x)
+        y_ep, _, _ = jax.jit(lambda p, x: moe_ffn_ep(p, cfg, x))(p, x)
         cfg2 = dataclasses.replace(cfg, fsdp=True)
-        y_fs, _ = jax.jit(lambda p, x: moe_ffn_ep(p, cfg2, x))(p, x)
+        y_fs, _, _ = jax.jit(lambda p, x: moe_ffn_ep(p, cfg2, x))(p, x)
     assert float(jnp.max(jnp.abs(y_ep - y_ref))) < 1e-5
     assert float(jnp.max(jnp.abs(y_fs - y_ref))) < 1e-5
     print("EP_OK")
@@ -53,5 +53,5 @@ def test_moe_ep_falls_back_without_mesh():
                               moe_ep=True)
     p = init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 4, cfg.d_model))
-    y, aux = moe_ffn(p, cfg, x)
+    y, aux, _ = moe_ffn(p, cfg, x)
     assert y.shape == x.shape and bool(jnp.isfinite(aux))

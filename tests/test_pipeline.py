@@ -170,6 +170,33 @@ def test_sg4_pipelined_snapshot_raim5_roundtrip():
         g.close()
 
 
+def test_finished_flight_and_closed_group_pin_no_state():
+    """A published flight drops its device leaves (the trainer's state
+    goes when the trainer drops it), and a closed group drops its
+    template: after training nothing of the saving path holds a state
+    on the device."""
+    import gc
+    from repro.core import ReftGroup
+    state = opt_state(1 << 12, seed=3)
+    cfg = ReftConfig(bucket_bytes=512, stage_slots=4,
+                     ckpt_dir=tempfile.mkdtemp(),
+                     checkpoint_every_snapshots=10 ** 6)
+    g = ReftGroup(2, state, cfg)
+    try:
+        g.snapshot(state, 1)
+        g.wait()
+        flights = [e._pipeline._last for e in g.engines]
+        assert all(f is not None and not f.in_flight() for f in flights)
+        assert all(f.leaves is None and f.encoder is None for f in flights)
+    finally:
+        g.close()
+    assert g.template is None
+    ids = {id(x) for x in jax.tree.leaves(state)}
+    del state
+    gc.collect()
+    assert not ids & {id(x) for x in jax.live_arrays()}
+
+
 # ------------------------------------------------------- wait() semantics
 @pytest.mark.parametrize("pipelined", [True, False])
 def test_wait_timeout_keeps_flight_live(pipelined):

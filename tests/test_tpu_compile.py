@@ -103,12 +103,13 @@ def test_leaf_gather_transient_within_twice_the_bucket(one_chip, shape,
 
 
 # ------------------------------------------------ train-step attention
-def _attention_grad(cfg, S, unroll=False, band=None):
+def _attention_grad(cfg, S, unroll=False, band=None, window=None):
     """d(sum of one attention layer's output)/d(params, x) under
-    jax.checkpoint, as the train step's remat scan body takes it."""
+    jax.checkpoint, as the train step's remat scan body takes it.
+    window: as the layer stack gives it -- None (full) or a Python int,
+    or a traced int32 where the local:global pattern does not tile the
+    depth."""
     from repro.models.attention import attention
-    from repro.models.layers import FULL_WINDOW
-    window = jnp.int32(cfg.sliding_window or FULL_WINDOW)
     positions = jnp.arange(S, dtype=jnp.int32)
 
     def loss(p, x):
@@ -142,20 +143,23 @@ def test_causal_attention_lowers_to_kernel_for_v5e(one_chip):
 @pytest.mark.parametrize("case", ["sliding_window", "banded", "unroll",
                                   "non_causal"])
 def test_other_attention_keeps_the_loops_for_v5e(one_chip, case):
-    """Windowed, banded, unrolled (dry-run) and bidirectional attention
-    lower to the pure-JAX loops on a TPU too."""
+    """A window traced through the scan (a local:global pattern that
+    does not tile the depth), banded, unrolled (dry-run) and
+    bidirectional attention lower to the pure-JAX loops on a TPU too."""
     import dataclasses
     from repro.configs import get_config
     cfg = get_config("opt-350m")
-    band, unroll = None, False
+    band, unroll, window = None, False, None
     if case in ("sliding_window", "banded"):
         cfg = dataclasses.replace(cfg, sliding_window=512)
         band = 512 if case == "banded" else None
+        window = jnp.int32(512) if case == "sliding_window" else 512
     elif case == "unroll":
         unroll = True
     else:
         cfg = dataclasses.replace(cfg, causal=False)
-    lowered = jax.jit(_attention_grad(cfg, 2048, unroll=unroll, band=band)) \
+    lowered = jax.jit(_attention_grad(cfg, 2048, unroll=unroll, band=band,
+                                      window=window)) \
         .lower(*_attention_args(cfg, 1, 2048, one_chip))
     assert "tpu_custom_call" not in lowered.as_text()
 
@@ -167,3 +171,67 @@ def test_causal_attention_lowers_to_the_loops_on_cpu():
     text = jax.jit(_attention_grad(cfg, 2048)) \
         .lower(*_attention_args(cfg, 4, 2048)).as_text()
     assert "custom_call" not in text
+
+
+# ------------------------------------------------------ whole train steps
+def _step_for_v5e(one_chip, arch, B, S, **over):
+    """The train step of `arch` (with `over` replaced) compiled for a
+    described v5e: (HLO text, temp bytes, {kernel name: count})."""
+    import collections
+    import dataclasses
+    import re
+    from repro.configs import get_config
+    from repro.optim.adam import AdamConfig
+    from repro.train.steps import init_train_state, make_train_step
+    cfg = dataclasses.replace(get_config(arch), **over)
+    state = jax.eval_shape(lambda: init_train_state(cfg, 0).tree())
+    state = jax.tree.map(lambda t: _spec(t.shape, t.dtype, one_chip), state)
+    tok = _spec((B, S), jnp.int32, one_chip)
+    compiled = jax.jit(make_train_step(cfg, AdamConfig(
+        moments_dtype="float32"))).lower(
+        state, {"tokens": tok, "labels": tok}).compile()
+    text = compiled.as_text()
+    kernels = collections.Counter(re.findall(
+        r"%(splash_mqa_\w+?|gmm|tgmm)(?:\.\d+)? = ", text))
+    return text, compiled.memory_analysis().temp_size_in_bytes, kernels
+
+
+def _whiles_under(text, scope):
+    import re
+    return [n for n in re.findall(r'%while\S* = [^\n]*op_name="([^"]*)"',
+                                  text) if f"/{scope}/" in n]
+
+
+def test_mellum2_step_lowers_to_kernels_for_v5e(one_chip):
+    """Mellum2's cut (4 layers: 3 windowed + 1 full; 8 of 64 experts held;
+    B 2, S 8192) at its published widths: every layer's attention is the
+    splash kernel (forward twice under remat, dq, dkv), with no loop under
+    `attention`; the experts are megablox's grouped products (3 forward,
+    3 recomputed, 3 input-gradient `gmm` and 3 weight-gradient `tgmm` a
+    layer).  Temp 2.88 GB when written; the cell holds about 3.3 copies of
+    W (3.40 GB) beside it on a 16 GiB chip, so over 4.5 GB it would not
+    fit."""
+    text, temp, kernels = _step_for_v5e(
+        one_chip, "mellum2-12b-a2.5b", 2, 8192, num_layers=4,
+        vocab_size=12288, experts_held=8)
+    assert kernels == {"splash_mqa_fwd_residuals": 8,
+                       "splash_mqa_dq_no_residuals": 4,
+                       "splash_mqa_dkv_no_residuals": 4,
+                       "gmm": 36, "tgmm": 12}
+    assert _whiles_under(text, "attention") == []
+    assert temp < 4.5e9
+
+
+def test_opt_step_lowering_kept_for_v5e(one_chip):
+    """opt-350m's cut (12 layers, B 4, S 2048) lowers as before the
+    windowed and sparse-expert paths came: one scanned layer with the
+    splash kernel (forward twice, dq, dkv), nothing of the MoE layer, and
+    the same temp as then (2,481,453,056 B)."""
+    text, temp, kernels = _step_for_v5e(one_chip, "opt-350m", 4, 2048,
+                                        num_layers=12)
+    assert kernels == {"splash_mqa_fwd_residuals": 2,
+                       "splash_mqa_dq_no_residuals": 1,
+                       "splash_mqa_dkv_no_residuals": 1}
+    assert "moe." not in text
+    assert _whiles_under(text, "attention") == []
+    assert temp == 2_481_453_056
