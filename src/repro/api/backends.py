@@ -202,7 +202,9 @@ class ReftCheckpointer(Checkpointer):
                 self.emit("snapshot-published", r.step,
                           seconds=r.wall_seconds, nbytes=r.bytes_sent,
                           levels=r.levels(), detail=f"node{e.node}",
-                          t_start=r.t_start, t_published=r.t_published)
+                          t_start=r.t_start, t_published=r.t_published,
+                          gather_bytes=r.gather_bytes,
+                          gather_packed_bytes=r.gather_packed_bytes)
 
     def set_dirty_provider(self, fn) -> None:
         """Install the delta saving path's dirtiness signal on every
@@ -428,6 +430,8 @@ class ReftCheckpointer(Checkpointer):
                 s.get("persist_upload_retries", 0) for s in eng)
         for k, v in self.group.level_seconds().items():
             out[f"engine_{k}_seconds"] = v
+        for k in ("gather_bytes", "gather_packed_bytes"):
+            out[f"engine_{k}"] = sum(s.get(k, 0) for s in eng)
         return out
 
     # ----------------------------------------------------------- faults

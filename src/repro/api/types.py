@@ -45,9 +45,13 @@ class CkptEvent:
     levels: Optional[Dict[str, float]] = None
     wall: float = field(default_factory=time.time)
     # snapshot-published only (one per member flight): the flight's start
-    # and publish times on the `time.perf_counter` clock
+    # and publish times on the `time.perf_counter` clock, and the leaf
+    # bytes its device gather moved (`PipelineResult.gather_bytes`) and
+    # packed from 8- and 16-bit leaves (`.gather_packed_bytes`)
     t_start: Optional[float] = None
     t_published: Optional[float] = None
+    gather_bytes: Optional[int] = None
+    gather_packed_bytes: Optional[int] = None
 
 
 @dataclass(frozen=True)

@@ -466,16 +466,20 @@ class DeviceEncoder:
     """Device-side bucket encode for one flight: gathers a `BucketTask`'s
     scattered leaf byte-ranges into a contiguous uint32 lane buffer *on
     the accelerator* (`repro.kernels.stage.place_bytes`: a windowed
-    slice of each leaf in its own layout, packed into words with shifts),
-    then runs the encode kernels (`repro.kernels.stage.encode_bucket`) —
-    XOR parity fold for kind-2 buckets, CRC32 for own-data buckets — and
-    pre-warms the d2h copy.  The host receives ready-to-publish bytes +
-    digest; no per-leaf host gather, no host XOR, no host zlib."""
+    slice of each leaf in its own layout, bitcast to words, or packed
+    into words by the `pack_words16` / `pack_words8` kernel for 8- and
+    16-bit leaves), then runs the encode kernels
+    (`repro.kernels.stage.encode_bucket`) — XOR parity fold for kind-2
+    buckets, CRC32 for own-data buckets — and pre-warms the d2h copy.
+    The host receives ready-to-publish bytes + digest; no per-leaf host
+    gather, no host XOR, no host zlib.  `gather_bytes` counts the leaf
+    bytes gathered (a parity bucket's every source), `gather_packed_bytes`
+    those of them that went through the pack kernel."""
 
     def __init__(self, spec: FlatSpec, leaves: List[Any]):
         import jax.numpy as jnp
         from repro.kernels.stage import (LANE_BYTES, bucket_crc,
-                                         encode_bucket, place_bytes)
+                                         encode_bucket, packs, place_bytes)
         self._jnp = jnp
         self._lane_bytes = LANE_BYTES
         self._encode = encode_bucket
@@ -484,6 +488,9 @@ class DeviceEncoder:
         self.spec = spec
         self.leaves = leaves
         self.offsets = [l.offset for l in spec.leaves]
+        self._packed = [packs(l.dtype) for l in leaves]
+        self.gather_bytes = 0
+        self.gather_packed_bytes = 0
 
     def gather_lanes(self, lo: int, hi: int):
         """Bytes [lo, hi) of the flat stream as (n_lanes,) uint32 on
@@ -499,6 +506,9 @@ class DeviceEncoder:
             if b > a:
                 out = self._place(out, self.leaves[i], a - ls.offset,
                                   a - lo, b - a)
+                self.gather_bytes += b - a
+                if self._packed[i]:
+                    self.gather_packed_bytes += b - a
             pos = b
             i += 1
         return out
@@ -572,6 +582,10 @@ class PipelineResult:
     delta_base: Optional[int] = None    # base step of a delta flight
     digests: Optional[Dict[int, int]] = None   # task idx -> bucket CRC32
     sent_extents: Tuple[Tuple[int, int], ...] = ()   # buffer-local, merged
+    # ---- device gather (0 on the host path): leaf bytes gathered into
+    # buckets, and those of 8- and 16-bit leaves, packed by the kernel
+    gather_bytes: int = 0
+    gather_packed_bytes: int = 0
 
     def levels(self) -> Dict[str, float]:
         return {k: getattr(self, f"{k}_seconds") for k in LEVELS}
@@ -889,6 +903,7 @@ class PipelineFlight:
             with span("repro.hasc.l3.publish", sec, "l3"):
                 clean = self._publish(meta, crcs)
             t_published = time.perf_counter()
+            enc = self.encoder             # the pump is done with it
             self.result = PipelineResult(
                 step=self.step, clean_step=clean, bytes_sent=sent,
                 l1_seconds=sec["l1_dispatch"] + sec["l1_d2h"],
@@ -902,7 +917,9 @@ class PipelineFlight:
                 delta_base=None if delta is None else delta.base_step,
                 digests=dict(self._digests) if self.want_digests else None,
                 sent_extents=tuple(merge_ranges(extents))
-                if self.want_digests else ())
+                if self.want_digests else (),
+                gather_bytes=enc.gather_bytes if enc else 0,
+                gather_packed_bytes=enc.gather_packed_bytes if enc else 0)
         except BaseException as e:
             if self.error is None:
                 self.error = e

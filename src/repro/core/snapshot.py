@@ -168,6 +168,7 @@ class SnapshotEngine:
         self._persists: Dict[int, dict] = {}    # seq -> in-flight record
         self.stats = {"snapshots": 0, "bytes_sent": 0, "seconds": 0.0,
                       **{f"{k}_seconds": 0.0 for k in LEVELS},
+                      "gather_bytes": 0, "gather_packed_bytes": 0,
                       "overlapped_flights": 0,
                       "persists": 0, "persist_inflight": 0,
                       "persist_seconds": 0.0,
@@ -336,6 +337,8 @@ class SnapshotEngine:
         st["seconds"] += res.wall_seconds
         for k, v in res.levels().items():
             st[f"{k}_seconds"] += v
+        st["gather_bytes"] += res.gather_bytes
+        st["gather_packed_bytes"] += res.gather_packed_bytes
         self.published.append(res)
         if self._pipeline is not None:
             st["stager_affinity"] = self._pipeline.applied_affinity

@@ -3,7 +3,9 @@
 The save hot path's per-bucket host work (gather -> XOR parity -> zlib
 CRC) moves onto the accelerator: the L1 pump gathers a bucket's scattered
 leaf byte-ranges into one contiguous uint32 lane buffer on device
-(`repro.core.pipeline.DeviceEncoder`), and `encode_bucket` finishes the
+(`repro.core.pipeline.DeviceEncoder`, `place_bytes`: 32-bit leaves are
+bitcast to words, 8- and 16-bit ones packed into words by the lane-dense
+`pack_words16` / `pack_words8` kernel), and `encode_bucket` finishes the
 encode *before* the d2h copy —
 
   * XOR-folds the k stacked stripe blocks of a parity bucket with the
@@ -189,25 +191,92 @@ def _window_geometry(shape, itemsize: int, n_out: int):
     return unit, count, min(count, (4 * n_out) // unit + 2)
 
 
-def _pack_words(win):
-    """Flat leaf window -> little-endian uint32 words (zero-padded to a
-    whole word), built with shifts so no narrow-minor-dim array exists."""
+PACK_ROWS = 128          # rows the pack kernel transposes at a time
+PACK_MAX_ROW = 4096      # widest leaf row the kernel reads in place
+PACK_WORDS = 512         # words per row of a window it reads flattened
+PACK_BLOCK_BYTES = 1 << 20   # input bytes per grid step
+
+
+def packs(dtype) -> bool:
+    """True iff leaves of `dtype` are packed into words by the kernel (8-
+    and 16-bit, bool as uint8); 32-bit leaves are bitcast."""
+    return np.dtype(dtype).itemsize < 4
+
+
+def _pack_kernel(x_ref, o_ref, t_ref, *, per: int):
+    """(tr, W) 8/16-bit rows -> (tr, W / per) little-endian uint32 words.
+    A transpose turns each row's neighbouring lanes into neighbouring
+    sublanes, where strided loads take the `per` bytes of a word apart
+    without a lane gather; a second transpose restores the rows."""
+    bits = 32 // per
+    n = t_ref.shape[0] // per
+
+    def body(j, carry):
+        r = pl.ds(pl.multiple_of(j * PACK_ROWS, PACK_ROWS), PACK_ROWS)
+        t_ref[...] = x_ref[r, :].astype(jnp.uint32).T
+        w = t_ref[pl.ds(0, n, stride=per), :]
+        for i in range(1, per):
+            w = w | (t_ref[pl.ds(i, n, stride=per), :] << (bits * i))
+        o_ref[r, :] = w.T
+        return carry
+
+    jax.lax.fori_loop(0, x_ref.shape[0] // PACK_ROWS, body, 0)
+
+
+def pack_block_rows(rows: int, width: int, itemsize: int) -> int:
+    """Rows per grid step of the pack kernel over (rows, width) input
+    (rows >= `PACK_ROWS`): about `PACK_BLOCK_BYTES` of it, in whole
+    `PACK_ROWS` and no more than the rows hold."""
+    per_step = PACK_BLOCK_BYTES // (width * itemsize * PACK_ROWS)
+    return min(max(1, per_step), rows // PACK_ROWS) * PACK_ROWS
+
+
+def _pack(u, *, interpret: bool):
+    """(R, W) uint8/uint16 (R >= `PACK_ROWS`, W a whole number of 128
+    words) -> (R, W / per) uint32 words of the same bytes.  The last grid
+    step may run past R: those rows are never written back."""
+    rows, width = u.shape
+    isz = u.dtype.itemsize
+    per = 4 // isz
+    tr = pack_block_rows(rows, width, isz)
+    return pl.pallas_call(
+        functools.partial(_pack_kernel, per=per),
+        grid=(pl.cdiv(rows, tr),),
+        in_specs=[pl.BlockSpec((tr, width), lambda g: (g, 0))],
+        out_specs=pl.BlockSpec((tr, width // per), lambda g: (g, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, width // per), jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((width, PACK_ROWS), jnp.uint32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name=f"pack_words{8 * isz}",
+    )(u)
+
+
+def _pack_words(win, *, interpret: bool):
+    """Leaf window ((rows, last axis) or (elements,)) -> its row-major
+    bytes as little-endian uint32 words, zero-padded past its end.
+    32-bit leaves are bitcast; 8- and 16-bit ones go through the pack
+    kernel, in the window's own rows where they are whole 128-word rows
+    of at most `PACK_MAX_ROW` elements, else as a flat view cut into
+    `PACK_WORDS`-word rows."""
     if win.dtype == jnp.bool_:
         win = win.astype(jnp.uint8)
     isz = win.dtype.itemsize
     if isz == 4:
-        return jax.lax.bitcast_convert_type(win, jnp.uint32)
+        return jax.lax.bitcast_convert_type(win.reshape(-1), jnp.uint32)
     if isz not in (1, 2):
         raise TypeError(f"no uint32 word view for {win.dtype} leaves")
     per = 4 // isz
     u = jax.lax.bitcast_convert_type(
         win, jnp.uint16 if isz == 2 else jnp.uint8)
-    if u.shape[0] % per:
-        u = jnp.pad(u, (0, per - u.shape[0] % per))
-    w = u[0::per].astype(jnp.uint32)
-    for i in range(1, per):
-        w = w | (u[i::per].astype(jnp.uint32) << (8 * isz * i))
-    return w
+    if u.ndim != 2 or u.shape[1] % (LANES * per) \
+            or u.shape[1] > PACK_MAX_ROW:
+        width = PACK_WORDS * per
+        u = u.reshape(-1)
+        u = jnp.pad(u, (0, -u.shape[0] % width)).reshape(-1, width)
+    u = jnp.pad(u, ((0, max(0, PACK_ROWS - u.shape[0])), (0, 0)))
+    return _pack(u, interpret=interpret).reshape(-1)
 
 
 def _byte_mask(k):
@@ -218,24 +287,24 @@ def _byte_mask(k):
 
 
 def _take_window(leaf, u0, uw: int):
-    """Flat window of `uw` units of `leaf` starting at unit `u0`."""
+    """Window of `uw` units of `leaf` starting at unit `u0`: (uw, last
+    axis) rows of an N-D leaf, (uw,) elements of a 0/1-D one."""
     if leaf.ndim <= 1:
         return jax.lax.dynamic_slice(leaf.reshape(-1), (u0,), (uw,))
     rows = leaf.reshape(-1, leaf.shape[-1])
-    return jax.lax.dynamic_slice(rows, (u0, 0),
-                                 (uw, leaf.shape[-1])).reshape(-1)
+    return jax.lax.dynamic_slice(rows, (u0, 0), (uw, leaf.shape[-1]))
 
 
 _window = jax.jit(_take_window, static_argnames=("uw",))
 
 
-@jax.jit
-def _place(out, leaf, u0, s, dst, n):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _place(out, leaf, u0, s, dst, n, *, interpret: bool = False):
     """`out` (n_out,) uint32 with its bytes [dst, dst+n) replaced by the
     leaf bytes that start `s` bytes into the window at unit `u0`."""
     n_out = out.shape[0]
     _, _, uw = _window_geometry(leaf.shape, leaf.dtype.itemsize, n_out)
-    p = _pack_words(_take_window(leaf, u0, uw))
+    p = _pack_words(_take_window(leaf, u0, uw), interpret=interpret)
     # output word q holds window bytes [4q + d, 4q + d + 4): a funnel shift
     # of words q + dw and q + dw + 1 by db bytes
     d = s - dst
@@ -322,7 +391,8 @@ def place_bytes(out, leaf, src: int, dst: int, n: int):
         win = jax.device_put(_window(leaf, np.int32(u0), uw=uw), dev)
         return place_bytes(out, win, s, dst, n)
     return _place(out, leaf, np.int32(u0), np.int32(s),
-                  np.int32(dst), np.int32(n))
+                  np.int32(dst), np.int32(n),
+                  interpret=runs_interpreted(out))
 
 
 def bucket_crc(crc, nbytes: int) -> int:

@@ -143,6 +143,111 @@ def test_xor_parity_bytes_roundtrip(nbytes):
         np.testing.assert_array_equal(rec, blocks[missing])
 
 
+# ------------------------------------------------------ device byte gather
+def _leaf(shape, dtype, seed):
+    """A CPU leaf of random bits (NaN and subnormal patterns included)."""
+    rng = np.random.default_rng(seed)
+    if dtype == np.bool_:
+        return jnp.asarray(rng.integers(0, 2, shape).astype(bool))
+    n = int(np.prod(shape)) * np.dtype(dtype).itemsize
+    raw = rng.integers(0, 256, n, dtype=np.uint8)
+    return jnp.asarray(raw.view(dtype).reshape(shape))
+
+
+def _check_place(leaf, src, n, dst, out_bytes, seed=0):
+    """`place_bytes` of leaf bytes [src, src+n) into bytes [dst, dst+n) of
+    a random bucket changes those bytes, to the leaf's row-major bytes
+    (as numpy and the host path's `LeafReader` give them), and no other."""
+    from repro.core.pipeline import LeafReader
+    from repro.core.treebytes import make_flat_spec
+    from repro.kernels.stage import place_bytes
+    raw = np.asarray(leaf).tobytes()
+    host = np.empty(n, np.uint8)
+    LeafReader(make_flat_spec({"x": leaf}), [leaf]).read(src, src + n, host)
+    assert host.tobytes() == raw[src:src + n]
+    rng = np.random.default_rng(seed)
+    base = rng.integers(0, 2 ** 32, out_bytes // 4, dtype=np.uint64) \
+        .astype(np.uint32)
+    got = np.asarray(place_bytes(jnp.asarray(base), leaf, src, dst, n))
+    want = bytearray(base.tobytes())
+    want[dst:dst + n] = raw[src:src + n]
+    assert got.tobytes() == bytes(want)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, np.float16, np.uint8,
+                                   np.bool_])
+@pytest.mark.parametrize("shape", [(3, 4096), (5, 768), (7, 896),
+                                   (2, 50272), (300, 256), (1001,)])
+def test_place_bytes_packs_narrow_leaves(dtype, shape):
+    """8- and 16-bit leaves (bool as uint8) go through the pack kernel
+    (interpreted here): the whole leaf, a piece from an odd element, and
+    one from an odd byte into an odd bucket byte, each with its tail."""
+    from repro.kernels.stage import packs
+    leaf = _leaf(shape, dtype, seed=len(shape) * shape[-1])
+    assert packs(leaf.dtype)
+    nb = leaf.nbytes
+    out_bytes = 1 << 16
+    isz = leaf.dtype.itemsize
+    for src, dst in [(0, 0), (3 * isz, 4), (5, 3), (nb // 2 + 1, 7)]:
+        n = min(nb - src, out_bytes - dst)
+        _check_place(leaf, src, n, dst, out_bytes, seed=src)
+
+
+@pytest.mark.parametrize("shape", [(600, 896), (700, 768)])
+def test_place_bytes_pack_kernel_runs_several_blocks(shape):
+    """A 1 MiB bucket: the pack kernel's grid takes two steps, the last
+    one past the window's rows (flattened 896-element rows; 768-element
+    rows read in place)."""
+    from repro.kernels.stage import pack_block_rows
+    out_bytes = 1 << 20
+    leaf = _leaf(shape, jnp.bfloat16, seed=shape[0])
+    # windows of 587 rows (514 rows of 1024 when flat) and of 684 rows
+    assert pack_block_rows(514, 1024, 2) == 512
+    assert pack_block_rows(684, 768, 2) == 640
+    n = min(leaf.nbytes - 2, out_bytes - 1)
+    _check_place(leaf, 2, n, 1, out_bytes)
+
+
+def test_place_bytes_keeps_32_bit_leaves_off_the_kernel():
+    """32-bit leaves are bitcast, as before: same bytes, no pack kernel."""
+    from repro.kernels.stage import packs
+    leaf = _leaf((9, 384), np.float32, seed=9)
+    assert not packs(leaf.dtype)
+    _check_place(leaf, 6, leaf.nbytes - 6, 1, 1 << 14)
+
+
+def test_gather_lanes_buckets_across_leaves():
+    """`DeviceEncoder.gather_lanes` over a state of mixed widths, in odd
+    4093-byte buckets (each crosses leaf boundaries and starts at an odd
+    byte; the last is a short tail): the flat stream's bytes, zero
+    padding to whole lanes, and counters that split the bytes by width."""
+    from repro.core.pipeline import DeviceEncoder, LeafReader
+    from repro.core.treebytes import leaf_arrays, make_flat_spec
+    from repro.kernels.stage import LANE_BYTES
+    state = {"a": _leaf((5, 896), jnp.bfloat16, 1),
+             "b": _leaf((33,), np.float32, 2),
+             "c": _leaf((1001,), np.uint8, 3),
+             "d": _leaf((7, 96), np.bool_, 4),
+             "e": _leaf((2, 768), np.float16, 5),
+             "f": _leaf((3, 128), np.float32, 6)}
+    spec = make_flat_spec(state)
+    leaves = leaf_arrays(state)
+    enc = DeviceEncoder(spec, leaves)
+    reader = LeafReader(spec, leaves)
+    total = spec.total_bytes
+    for lo in range(0, total, 4093):
+        hi = min(lo + 4093, total)
+        got = np.asarray(enc.gather_lanes(lo, hi)).view(np.uint8)
+        want = np.empty(hi - lo, np.uint8)
+        reader.read(lo, hi, want)
+        assert got.nbytes % LANE_BYTES == 0
+        assert got[:hi - lo].tobytes() == want.tobytes()
+        assert not got[hi - lo:].any()
+    wide = sum(l.nbytes for l in spec.leaves if l.dtype == "float32")
+    assert enc.gather_bytes == total
+    assert enc.gather_packed_bytes == total - wide
+
+
 # ------------------------------------------------------------- ssd_scan
 @pytest.mark.parametrize("B,S,H,P,N,Q", [
     (2, 64, 4, 8, 16, 16),

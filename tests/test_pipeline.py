@@ -400,6 +400,68 @@ def test_device_encode_byte_identical_to_host_path():
     assert probes["off"][2] == probes["on"][2], "own-region CRC differs"
 
 
+def _packed_share_of_schedule(spec, schedule):
+    """(gather bytes, of them in 8/16-bit leaves) of one flight's
+    schedule: every own bucket and every source of a fused parity one, up
+    to the state's end (the stripe padding past it is zeros, not read)."""
+    total = packed = 0
+    for t in schedule:
+        for lo, hi in (t.sources if t.kind == 2 else [(t.lo, t.hi)]):
+            hi = min(hi, spec.total_bytes)
+            total += max(0, hi - lo)
+            for l in spec.leaves:
+                if np.dtype(l.dtype).itemsize < 4:
+                    packed += max(0, min(hi, l.offset + l.nbytes)
+                                  - max(lo, l.offset))
+    return total, packed
+
+
+def test_device_encode_mixed_widths_byte_identical_and_packed_share():
+    """A state of f32 and bf16 leaves (odd rows, a 1-D bf16 vector):
+    the device path, whose bf16 pieces go through the pack kernel,
+    publishes the host path's own bytes, parity bytes and CRC, and its
+    counters give the schedule's 16-bit share (0 on the host path)."""
+    import pickle
+
+    from repro.core import ReftGroup
+    from repro.core.smp import ReadOnlyNode
+    k = jax.random.PRNGKey(7)
+    state = {"emb": jax.random.normal(k, (37, 768), jnp.bfloat16),
+             "mlp": jax.random.normal(k, (3, 7, 896), jnp.bfloat16),
+             "norm": jnp.ones((769,), jnp.bfloat16),
+             "opt": {"mu": jax.random.normal(k, (37, 768), jnp.float32),
+                     "nu": jnp.zeros((3, 7, 896), jnp.float32)}}
+    probes = {}
+    for mode in ("off", "on"):
+        cfg = ReftConfig(bucket_bytes=5000, stage_slots=4,
+                         device_encode=mode, ckpt_dir=tempfile.mkdtemp(),
+                         checkpoint_every_snapshots=10 ** 6)
+        g = ReftGroup(3, state, cfg)
+        try:
+            assert g.snapshot(state, 2)
+            view = ReadOnlyNode(g.run, 1, 3, g.total_bytes)
+            try:
+                probes[mode] = (view.read_own(2).tobytes(),
+                                view.read_parity(2).tobytes(),
+                                pickle.loads(view.meta(2))["crc_own"])
+            finally:
+                view.close()
+            total = sum(e.stats["gather_bytes"] for e in g.engines)
+            packed = sum(e.stats["gather_packed_bytes"] for e in g.engines)
+            if mode == "off":
+                assert total == packed == 0
+            else:
+                want = [_packed_share_of_schedule(e.spec,
+                                                  e._pipeline.schedule)
+                        for e in g.engines]
+                assert total == sum(t for t, _ in want)
+                assert packed == sum(p for _, p in want)
+                assert 0 < packed < total
+        finally:
+            g.close()
+    assert probes["off"] == probes["on"]
+
+
 def test_sg4_device_encode_raim5_roundtrip():
     """Full SG with device-encoded (kind-2) parity: single-node loss still
     decodes bit-identically from the kernel-encoded parity blocks."""
@@ -539,6 +601,30 @@ def test_reft_backend_reports_levels():
             assert st["engine_l3_seconds"] > 0
             ev = [e for e in ck.events if e.kind == "snapshot"][-1]
             assert ev.levels is not None and ev.levels["l1"] > 0
+
+
+def test_reft_backend_reports_gather_counters():
+    """The device gather's counters reach the facade: each member's
+    `snapshot-published` event carries its flight's, `stats()` their
+    sums; the bf16 leaf's bytes are the packed ones."""
+    from repro.api import CheckpointSpec
+    import tempfile
+    state = opt_state(1 << 12)
+    with tempfile.TemporaryDirectory() as d:
+        spec = CheckpointSpec(backend="reft", ckpt_dir=d, sg_size=2,
+                              resume=False, bucket_bytes=1 << 12,
+                              options={"device_encode": "on"})
+        with spec.build(state) as ck:
+            assert ck.snapshot(state, 1, wait=True)
+            st = ck.stats()
+            pub = [e for e in ck.events if e.kind == "snapshot-published"]
+            assert len(pub) == 2
+            assert st["engine_gather_bytes"] == sum(
+                e.gather_bytes for e in pub) > 0
+            assert st["engine_gather_packed_bytes"] == sum(
+                e.gather_packed_bytes for e in pub) > 0
+            assert st["engine_gather_packed_bytes"] < \
+                st["engine_gather_bytes"]
 
 
 def test_serial_fallback_via_options():

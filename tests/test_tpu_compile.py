@@ -102,6 +102,24 @@ def test_leaf_gather_transient_within_twice_the_bucket(one_chip, shape,
     assert mem.temp_size_in_bytes <= 2 * min(BUCKET, leaf_bytes)
 
 
+@pytest.mark.parametrize("shape", [
+    (12, 1024, 4096),              # opt-350m's stacked MLP weight
+    (50272, 768),                  # opt-125m's embedding
+    (1024, 50272),                 # opt-350m's head: 25,136 words a row
+    (1, 8, 2304, 896),             # mellum2's held experts: 448 words
+])
+def test_leaf_gather_packs_16bit_with_kernel_for_v5e(one_chip, shape):
+    """A bf16 leaf's gather packs words in the Pallas kernel: the program
+    has a `tpu_custom_call` and no element `gather`."""
+    from repro.kernels.stage import _place
+    out = _spec((BUCKET // 4,), jnp.uint32, one_chip)
+    leaf = _spec(shape, jnp.bfloat16, one_chip)
+    i32 = _spec((), jnp.int32, one_chip)
+    compiled = _place.lower(out, leaf, i32, i32, i32, i32).compile()
+    assert _is_kernel(compiled)
+    assert " gather(" not in compiled.as_text()
+
+
 # ------------------------------------------------ train-step attention
 def _attention_grad(cfg, S, unroll=False, band=None, window=None):
     """d(sum of one attention layer's output)/d(params, x) under
