@@ -1,10 +1,6 @@
 """Shared benchmark utilities."""
 from __future__ import annotations
 
-import time
-
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 
@@ -24,23 +20,3 @@ def make_param_state(nbytes: int, seed: int = 0) -> dict:
         "nu": jnp.zeros((n,), jnp.float32),
         "step": jnp.int32(0),
     }
-
-
-def timeit(fn, *args, repeat: int = 3, warmup: int = 1, **kw):
-    for _ in range(warmup):
-        fn(*args, **kw)
-    ts = []
-    for _ in range(repeat):
-        t0 = time.perf_counter()
-        fn(*args, **kw)
-        ts.append(time.perf_counter() - t0)
-    return min(ts)
-
-
-def row(name: str, seconds: float, derived: str = "") -> str:
-    us = seconds * 1e6
-    return f"{name},{us:.1f},{derived}"
-
-
-def tree_bytes(tree) -> int:
-    return sum(np.asarray(x).nbytes for x in jax.tree.leaves(tree))

@@ -11,6 +11,7 @@ through it.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import Any, List, Optional
 
@@ -101,6 +102,11 @@ def reft_recovery_ladder(run: str, n: int, total_bytes: int, template: Any,
                          tier="objstore", load=stats)
 
 
+# ReftConfig fields that selected the serial saving thread and overlapped
+# flights; naming one is an error, not a silent change of mode
+_REMOVED_REFT_OPTIONS = ("pipeline", "max_flights")
+
+
 class ReftCheckpointer(Checkpointer):
     """REFT behind the facade: async sharded in-memory snapshots (REFT-Sn),
     SMP-side persistence (REFT-Ckpt) with managed retention, ladder
@@ -112,58 +118,27 @@ class ReftCheckpointer(Checkpointer):
         super().__init__(spec)
         from repro.ckpt.manager import CheckpointManager
         from repro.core.coordinator import ReftGroup
-        from repro.core.snapshot import ReftConfig, _trace_default
+        from repro.core.snapshot import ReftConfig
 
         run_id = spec.run_id or CheckpointSpec.alloc_run_id()
-        opt = spec.options
-        rcfg = ReftConfig(
-            bucket_bytes=spec.bucket_bytes,
-            ckpt_dir=spec.ckpt_dir,
-            snapshot_every_steps=spec.snapshot_every_steps,
+        for key in _REMOVED_REFT_OPTIONS:
+            if key in spec.options:
+                raise ValueError(
+                    f"reft option {key!r} was removed: a member's engine "
+                    f"runs one pipelined snapshot flight at a time")
+        # options that name a ReftConfig field go to it as given (its
+        # defaults are the only ones); the rest are the session's and the
+        # objstore backend's (docs/API.md "Saving pipeline")
+        known = {f.name for f in dataclasses.fields(ReftConfig)}
+        rcfg = ReftConfig(**{
+            **{k: v for k, v in spec.options.items() if k in known},
+            "bucket_bytes": spec.bucket_bytes,
+            "ckpt_dir": spec.ckpt_dir,
+            "snapshot_every_steps": spec.snapshot_every_steps,
             # the session owns persist cadence; disable the group's own
-            checkpoint_every_snapshots=10 ** 9,
-            run_id=run_id,
-            stage_slots=opt.get("stage_slots", 8),
-            # HASC saving-pipeline knobs (docs/API.md "Saving pipeline");
-            # pipeline=False keeps the serial pre-refactor thread as the
-            # measurable interference baseline
-            pipeline=opt.get("pipeline", True),
-            prefetch_window=opt.get("prefetch_window", 4),
-            scratch_buffers=opt.get("scratch_buffers", 2),
-            opt_first=opt.get("opt_first", True),
-            yield_every_buckets=opt.get("yield_every_buckets", 4),
-            boundary_timeout_s=opt.get("boundary_timeout_s", 0.005),
-            # device-side encode + multi-flight (docs/API.md
-            # "Device-side encode"): device gather + Pallas XOR/CRC before
-            # d2h, overlapped flights, saving-path CPU pinning
-            device_encode=opt.get("device_encode", "auto"),
-            max_flights=opt.get("max_flights", 1),
-            pin_cpus=opt.get("pin_cpus", "auto"),
-            # async-persistence knobs (docs/API.md "Async persistence"):
-            # simulated durable-tier latency for tests and the
-            # persist-overlap interference benchmark; persist_bw_limit
-            # rate-limits the SMP's background writes (+ uploads) so the
-            # durable tier cannot starve a co-located trainer of IO
-            persist_delay_s=opt.get("persist_delay_s", 0.0),
-            persist_bw_limit=opt.get("persist_bw_limit", 0.0),
-            # dirty-delta snapshotting (docs/API.md "Delta snapshots &
-            # keyframes"): flights re-send only changed buckets, persists
-            # write `.reftd` chains against the last persisted step
-            delta=opt.get("delta", False),
-            delta_keyframe=opt.get("delta_keyframe", 8),
-            delta_dirty_threshold=opt.get("delta_dirty_threshold", 0.6),
-            delta_digest=opt.get("delta_digest", True),
-            # straggler-aware loading (docs/API.md "Straggler-aware
-            # loading"): restore-side read scheduler mode and token-bucket
-            # rate cap mirroring persist_bw_limit on the write side
-            restore_sched=opt.get("restore_sched", "adaptive"),
-            restore_bw_limit=opt.get("restore_bw_limit", 0.0),
-            # runtime SMP-protocol validation (docs/API.md "Analysis &
-            # invariants"); default follows REPRO_TRACE_PROTOCOL so CI
-            # turns it on fleet-wide without touching call sites
-            trace_protocol=bool(opt.get("trace_protocol",
-                                        _trace_default())),
-        )
+            "checkpoint_every_snapshots": 10 ** 9,
+            "run_id": run_id,
+        })
         self.group = ReftGroup(spec.sg_size, state_template, rcfg)
         self.manager = CheckpointManager(spec.ckpt_dir, spec.sg_size,
                                          keep=spec.keep)
@@ -253,7 +228,7 @@ class ReftCheckpointer(Checkpointer):
         still falls back to full shards when any member lacks the flight
         extents, and the engines' snapshot keyframes bound chain length
         (a keyframe in the span voids the chain)."""
-        if not self.spec.options.get("delta", False):
+        if not self.group.cfg.delta:
             return None
         steps = set(self.manager.complete_steps())
         if self.manager.store is not None:
@@ -408,12 +383,10 @@ class ReftCheckpointer(Checkpointer):
         out["persist_errors"] = sum(s.get("persist_errors", 0) for s in eng)
         out["persist_throttle_seconds"] = sum(
             s.get("persist_throttle_seconds", 0.0) for s in eng)
-        out["persist_bw_limit"] = float(
-            self.spec.options.get("persist_bw_limit", 0.0))
-        out["restore_bw_limit"] = float(
-            self.spec.options.get("restore_bw_limit", 0.0))
-        out["restore_sched"] = self.spec.options.get(
-            "restore_sched", "adaptive")
+        cfg = self.group.cfg
+        out["persist_bw_limit"] = float(cfg.persist_bw_limit)
+        out["restore_bw_limit"] = float(cfg.restore_bw_limit)
+        out["restore_sched"] = cfg.restore_sched
         out["skipped_buckets"] = sum(s.get("skipped_buckets", 0)
                                      for s in eng)
         out["delta_flights"] = sum(s.get("delta_flights", 0) for s in eng)

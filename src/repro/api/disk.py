@@ -28,8 +28,8 @@ import numpy as np
 
 from repro.api.registry import register_backend
 from repro.api.types import Checkpointer, CheckpointSpec, RestoreResult
+from repro.core.pipeline import LeafReader
 from repro.core.recovery import RecoveryError
-from repro.core.snapshot import _LeafReader
 from repro.core.treebytes import (
     FlatSpec, buffer_to_tree, leaf_arrays, make_flat_spec,
 )
@@ -108,7 +108,7 @@ class DiskWriter:
             times = PhaseTimes()
             # phase 1: d2h ("snapshotting") of every rank's range
             t0 = time.time()
-            reader = _LeafReader(self.spec, leaves)
+            reader = LeafReader(self.spec, leaves)
             bufs: Dict[int, np.ndarray] = {}
             for r in range(self.n_ranks):
                 lo, hi = self._rank_range(r)

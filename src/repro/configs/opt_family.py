@@ -1,6 +1,6 @@
 """OPT family — the paper's own evaluation models (§6.1) [arXiv:2205.01068].
 
-Used by the REFT benchmarks (weak/strong scaling over OPT-125M..2.7B).
+The benchmark's opt-125m and opt-350m cells (bench/configs) train these.
 """
 from repro.configs.base import ModelConfig, register
 

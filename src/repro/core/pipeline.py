@@ -29,14 +29,11 @@ hardware allows:
                     produced per-bucket digests; the clean-ack completes
                     the flight.
 
-Multi-flight overlap: with ``max_flights > 1`` snapshot N+1's L1 pump may
-start while snapshot N drains L2/L3.  Flights chain on two events —
-N+1's pump waits for N's *pump* to finish (so the shared scratch-credit
-pool is drained oldest-first, deadlock-free), and N+1's stager waits for
-N's clean-ack before ``begin`` (so the SMP never holds two dirty
-buffers).  The scratch pool is owned by the pipeline, not the flight, so
-scratch memory stays fixed at ``scratch_buffers`` buckets no matter how
-many flights are in the air.
+One flight at a time: a member's pipeline launches the next flight only
+once the previous one has published (or failed), so the SMP holds at
+most one dirty buffer and the device holds one pinned copy of the state.
+The scratch-credit pool is owned by the pipeline, not the flight, and is
+topped back up to ``scratch_buffers`` credits at every launch.
 
 The flight keeps `snapshot_async`/`snapshot_sync`/`wait` semantics and the
 dirty-never-visible invariant: an aborted flight never sends ``end``, so
@@ -154,7 +151,7 @@ def resolve_device_encode(cfg, leaves: Sequence[Any]) -> bool:
     (interpret-mode kernels for CPU-resident leaves — what CI exercises),
     "off" forces the host path, "auto" takes the device path exactly when
     the leaves being saved live on an accelerator."""
-    return _resolve(getattr(cfg, "device_encode", "auto"), leaves)
+    return _resolve(cfg.device_encode, leaves)
 
 
 def resolve_ranged_fetch(cfg, leaves: Sequence[Any]) -> bool:
@@ -164,7 +161,7 @@ def resolve_ranged_fetch(cfg, leaves: Sequence[Any]) -> bool:
     live on an accelerator — for CPU-resident leaves `np.asarray` is
     already zero-copy, so device-side slicing is pure dispatch overhead
     there."""
-    return _resolve(getattr(cfg, "ranged_fetch", "auto"), leaves)
+    return _resolve(cfg.ranged_fetch, leaves)
 
 
 def resolve_affinity(pin) -> Optional[Tuple[int, ...]]:
@@ -599,16 +596,11 @@ class PipelineFlight:
     joined by credit queues.  `wait` never drops a live flight (a timeout
     raises and the flight stays current), and an aborted flight never
     sends `end`, so a half-written dirty buffer is never published.
-
-    Scratch credits come from the owning pipeline's SHARED pool; `prev`
-    chains multi-flight overlap (see module docstring): this flight's
-    pump starts after `prev`'s pump finished, its stager `begin`s after
-    `prev`'s clean-ack."""
+    Scratch credits come from the owning pipeline's pool."""
 
     def __init__(self, smp, spec: FlatSpec, cfg, schedule: List[BucketTask],
                  budget: Dict[int, int], leaves: List[Any], step: int,
                  extra_meta: dict, *, free: "queue.Queue",
-                 prev: "Optional[PipelineFlight]" = None,
                  encoder: Optional[DeviceEncoder] = None,
                  affinity: Optional[Tuple[int, ...]] = None,
                  pipeline: "Optional[SnapshotPipeline]" = None,
@@ -617,7 +609,6 @@ class PipelineFlight:
         self.smp, self.spec, self.cfg = smp, spec, cfg
         self.schedule, self.budget = schedule, budget
         self.leaves, self.step, self.extra_meta = leaves, step, extra_meta
-        self.prev = prev
         self.encoder = encoder
         self.affinity = affinity
         self.pipeline = pipeline
@@ -630,12 +621,11 @@ class PipelineFlight:
         self.result: Optional[PipelineResult] = None
         self.error: Optional[BaseException] = None
         self.done = threading.Event()
-        self.pump_done = threading.Event()
         self._abort = threading.Event()
         # set while a caller is blocked in wait(): the trainer cannot tick
         # step boundaries then, so the pump must not wait for them
         self._draining = threading.Event()
-        self._free = free                       # SHARED scratch-credit pool
+        self._free = free                       # the pipeline's credit pool
         self._ready: "queue.Queue" = queue.Queue()
         # seconds by level; the pump adds to the l1 keys, the stager to l2
         # and l3 (each key has one writer)
@@ -669,20 +659,8 @@ class PipelineFlight:
             with span("repro.hasc.l1.gate", self._sec, "l1_gate"):
                 GATE.wait_boundary(timeout)
 
-    def _wait_event(self, ev: threading.Event, what: str) -> None:
-        while not ev.wait(0.5):
-            if self._abort.is_set():
-                raise RuntimeError(
-                    f"snapshot pipeline aborted while waiting for {what}")
-
     def _pump(self):
         try:
-            prev = self.prev               # local: the stager clears the
-            if prev is not None:           # attr once this flight is done
-                # multi-flight: consume shared scratch credits strictly
-                # oldest-flight-first (no two pumps compete for the pool,
-                # so the older flight can always finish draining)
-                self._wait_event(prev.pump_done, "predecessor pump")
             if self.encoder is not None:
                 self._pump_device()
             else:
@@ -692,7 +670,6 @@ class PipelineFlight:
                 self.error = e
             self._abort.set()
         finally:
-            self.pump_done.set()
             self._ready.put(_STOP)
 
     def _work_items(self) -> List[Tuple[int, BucketTask]]:
@@ -712,9 +689,9 @@ class PipelineFlight:
         return out
 
     def _pump_host(self):
-        window = max(1, getattr(self.cfg, "prefetch_window", 4))
-        yield_every = max(0, getattr(self.cfg, "yield_every_buckets", 4))
-        yield_timeout = getattr(self.cfg, "boundary_timeout_s", 0.005)
+        window = max(1, self.cfg.prefetch_window)
+        yield_every = max(0, self.cfg.yield_every_buckets)
+        yield_timeout = self.cfg.boundary_timeout_s
         work = self._work_items()
         budget, extents = self.budget, None
         if self.delta is not None and len(work) < len(self.schedule):
@@ -786,9 +763,9 @@ class PipelineFlight:
 
     def _pump_device(self):
         enc = self.encoder
-        window = max(1, getattr(self.cfg, "prefetch_window", 4))
-        yield_every = max(0, getattr(self.cfg, "yield_every_buckets", 4))
-        yield_timeout = getattr(self.cfg, "boundary_timeout_s", 0.005)
+        window = max(1, self.cfg.prefetch_window)
+        yield_every = max(0, self.cfg.yield_every_buckets)
+        yield_timeout = self.cfg.boundary_timeout_s
         delta = self.delta
         digesting = self.want_digests
         # digest compare pending: hold the payload d2h until the 4-byte
@@ -845,12 +822,6 @@ class PipelineFlight:
             extents: List[Tuple[int, int]] = []        # buffer-local, sent
             own_bytes = self.smp.layout.own_bytes
             delta = self.delta
-            prev = self.prev
-            if prev is not None:
-                # the SMP holds at most one dirty buffer: begin only after
-                # the predecessor's clean-ack (its stager is done with the
-                # pipe, so the conn is ours alone from here)
-                self._wait_event(prev.done, "predecessor clean-ack")
             with span("repro.hasc.l3.begin", sec, "l3"):
                 if delta is not None:
                     # confirmed exchange: the SMP seeds the new shard
@@ -931,8 +902,6 @@ class PipelineFlight:
             # holds its own references)
             self.leaves = self.encoder = None
             self.done.set()
-            self.prev = None               # release the predecessor (and
-                                           # its pinned leaves) promptly
 
     def _publish(self, meta: dict,
                  crcs: List[Tuple[int, int, int]]) -> int:
@@ -1006,10 +975,8 @@ class PipelineFlight:
 
 class SnapshotPipeline:
     """Per-engine HASC driver: owns the (static) bucket schedule, leaf
-    budget, the SHARED scratch-credit pool, and the flight chain.
-    `start` launches a `PipelineFlight`; with `cfg.max_flights > 1` a new
-    flight may launch while predecessors drain (overlap), chained so
-    credits drain oldest-first and the SMP sees one dirty buffer."""
+    budget and scratch-credit pool.  `start` launches a `PipelineFlight`,
+    one at a time: the SMP holds one dirty buffer."""
 
     def __init__(self, smp, spec: FlatSpec, cfg,
                  own_plan: Sequence[Tuple[int, int, int]],
@@ -1022,23 +989,22 @@ class SnapshotPipeline:
         self.accelerator = on_accelerator(template_leaves)
         self.device_encode = resolve_device_encode(cfg, template_leaves)
         self.ranged_fetch = resolve_ranged_fetch(cfg, template_leaves)
-        self.max_flights = max(1, int(getattr(cfg, "max_flights", 1)))
-        self.delta_enabled = bool(getattr(cfg, "delta", False))
+        self.delta_enabled = bool(cfg.delta)
         # delta mode always fuses parity (host path included): a delta
         # flight refreshes affected parity extents with fully-folded plain
         # writes — XOR-accumulate (kind 1) would need the base parity
         # zeroed first, which the base-copy begin precisely must not do
         self.schedule = build_schedule(
             spec, own_plan, stripe_plan, cfg.bucket_bytes,
-            opt_first=getattr(cfg, "opt_first", True),
+            opt_first=cfg.opt_first,
             fuse_parity=self.device_encode or self.delta_enabled)
         self.budget = leaf_budget(
             spec, [(lo, hi) for _, lo, hi in own_plan] + list(stripe_plan))
-        self.scratch_buffers = max(1, getattr(cfg, "scratch_buffers", 2))
+        self.scratch_buffers = max(1, cfg.scratch_buffers)
         self._free: "queue.Queue" = queue.Queue()
         for _ in range(self.scratch_buffers):
             self._free.put(self._new_credit())
-        self.affinity = resolve_affinity(getattr(cfg, "pin_cpus", None))
+        self.affinity = resolve_affinity(cfg.pin_cpus)
         self.applied_affinity: Optional[Tuple[int, ...]] = None
         self._last: Optional[PipelineFlight] = None
 
@@ -1049,42 +1015,30 @@ class SnapshotPipeline:
             else np.empty(self.cfg.bucket_bytes, np.uint8)
 
     def _replenish(self) -> None:
-        """Top the shared pool back up (idle only): a flight that died
-        mid-drain may have stranded credits with its corpse."""
+        """Top the pool back up (idle only): a flight that died mid-drain
+        may have stranded credits with its corpse."""
         while self._free.qsize() < self.scratch_buffers:
             self._free.put(self._new_credit())
 
-    def live_flights(self) -> int:
-        n, f = 0, self._last
-        while f is not None and f.in_flight():
-            n += 1
-            f = f.prev
-        return n
-
     def start(self, leaves: List[Any], step: int, extra_meta: dict,
               delta: Optional[FlightDelta] = None) -> PipelineFlight:
-        if self.live_flights() >= self.max_flights:
+        if self._last is not None and self._last.in_flight():
             # the engine refuses before calling; this is the backstop for
-            # direct callers — the flight chain (and the SMP's triple
-            # buffer) is sized for max_flights
+            # direct callers — the SMP holds one dirty buffer
             raise RuntimeError(
-                f"max_flights={self.max_flights} snapshots already in "
-                f"flight")
+                f"snapshot for step {self._last.step} still in flight")
         if on_accelerator(leaves) != self.accelerator:
             raise ValueError(
                 f"snapshot leaves are {'' if self.accelerator else 'not '}"
                 f"expected on an accelerator (the engine's state template "
                 f"was{'' if self.accelerator else ' not'}): build the "
                 f"engine from a template placed like the state it saves")
-        prev = self._last if (self._last is not None
-                              and self._last.in_flight()) else None
-        if prev is None:
-            self._replenish()
+        self._replenish()
         encoder = DeviceEncoder(self.spec, leaves) \
             if self.device_encode else None
         flight = PipelineFlight(
             self.smp, self.spec, self.cfg, self.schedule, self.budget,
-            leaves, step, extra_meta, free=self._free, prev=prev,
+            leaves, step, extra_meta, free=self._free,
             encoder=encoder, affinity=self.affinity, pipeline=self,
             delta=delta, want_digests=self.delta_enabled)
         self._last = flight

@@ -184,7 +184,7 @@ def _smp_main(conn, run: str, node: int, n: int, total_bytes: int,
             try:
                 if delay_s:                  # simulated slow durable tier
                     # analyze: ok ANZ007 — injected latency, not polling
-                    time.sleep(delay_s)      # (tests / interference bench)
+                    time.sleep(delay_s)      # (tests / slow-persist faults)
                 # one token bucket covers the local stream AND the remote
                 # upload: persist_bw_limit bounds the SMP's total write
                 # pressure against a co-located trainer
@@ -783,7 +783,7 @@ class SMPHandle:
         `persist_wait`/`persist_poll` take).  The SMP services it on a
         background thread, so snapshots keep flowing while the shard
         streams to disk.  `delay_s` simulates a slow durable tier (tests
-        and the interference benchmark).  `opts` is a plain picklable
+        and the slow-persist fault).  `opts` is a plain picklable
         dict of worker knobs: `bw_limit` (token-bucket bytes/s for the
         write stream) and `remote` (`{store, key, retry}` — mirror the
         shard to an object store after the local write)."""

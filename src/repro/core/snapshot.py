@@ -9,37 +9,26 @@ reference to the step-t state pins a consistent snapshot for free — no
 GPU-side tensor duplication is needed before the async d2h copy, unlike the
 PyTorch original.
 
-`SnapshotEngine` is a thin facade: the saving hot path is the hierarchical
+`SnapshotEngine` is a thin facade: the saving path is the hierarchical
 async pipeline in `repro.core.pipeline` (L1 device pump / L2 host stager /
-L3 event-driven SMP — HASC).  ``ReftConfig(pipeline=False)`` keeps the
-pre-refactor single serial thread (read -> CRC -> blocking ring send per
-bucket) as a measurable baseline for the pipeline's interference win.
+L3 event-driven SMP — HASC), one flight at a time per member.
 """
 from __future__ import annotations
 
 import collections
-import pickle
-import threading
 import time
 import uuid
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
-import numpy as np
-
 from repro.core import raim5
 from repro.core.delta import DeltaLog, DeltaTracker
-from repro.core.pipeline import (LEVELS, DeltaBaseMismatch, LeafReader,
-                                 PipelineFlight, PipelineResult,
-                                 SnapshotPipeline, leaf_budget,
+from repro.core.pipeline import (LEVELS, DeltaBaseMismatch, PipelineFlight,
+                                 PipelineResult, SnapshotPipeline,
                                  resolve_affinity)
 from repro.core.smp import NodeLayout, SMPHandle
 from repro.core.spans import span
-from repro.core.treebytes import FlatSpec, leaf_arrays, make_flat_spec
-
-# Back-compat alias: the reader grew eviction budgets and moved into the
-# pipeline module where both the pipelined and serial paths share it.
-_LeafReader = LeafReader
+from repro.core.treebytes import leaf_arrays, make_flat_spec
 
 
 def _trace_default() -> bool:
@@ -56,28 +45,24 @@ class ReftConfig:
     ckpt_dir: str = "/tmp/reft-ckpt"
     run_id: str = field(default_factory=lambda: uuid.uuid4().hex[:8])
     # --- HASC pipeline knobs (repro.core.pipeline) ---
-    pipeline: bool = True            # False = pre-refactor serial thread
     prefetch_window: int = 4         # buckets of copy_to_host_async ahead
     scratch_buffers: int = 2         # double-buffered L1 scratch fills
     opt_first: bool = True           # drain optimizer-moment leaves first
     yield_every_buckets: int = 4     # L1 yields to training this often
     boundary_timeout_s: float = 0.005  # max wait for a step boundary
-    # --- device-side encode + multi-flight (docs/API.md) ---
+    # --- device-side encode (docs/API.md) ---
     device_encode: str = "auto"      # "auto" (on iff the saved leaves
                                      # live on an accelerator) | "on" | "off"
-    max_flights: int = 1             # >1: snapshot N+1's L1 may overlap
-                                     # snapshot N's L2/L3 drain
     pin_cpus: Any = "auto"           # saving-path CPU set for the L2
                                      # stager + SMP: "auto" | "off" | ids
     # --- async REFT-Ckpt persistence (docs/API.md "Async persistence") ---
     persist_delay_s: float = 0.0     # simulated durable-tier latency per
-                                     # persist (tests / interference bench)
+                                     # persist (tests / slow-persist faults)
     persist_bw_limit: float = 0.0    # token-bucket cap (bytes/s) on the
                                      # SMP's background persist + upload
                                      # writes; 0 = unlimited
     # --- dirty-delta snapshots (docs/API.md "Delta snapshots") ---
     delta: bool = False              # delta flights between full keyframes
-                                     # (requires pipeline=True, max_flights=1)
     delta_keyframe: int = 8          # force a full keyframe every N flights
     delta_dirty_threshold: float = 0.6   # dirty fraction above which a
                                      # delta saves nothing -> keyframe
@@ -116,7 +101,7 @@ class SnapshotEngine:
         self.run = run_id or cfg.run_id
         self.spec = make_flat_spec(state_template)
         self.layout = NodeLayout(n, self.spec.total_bytes)
-        affinity = resolve_affinity(getattr(cfg, "pin_cpus", None))
+        affinity = resolve_affinity(cfg.pin_cpus)
         self.smp = SMPHandle(self.run, node, n, self.spec.total_bytes,
                              stage_slots=cfg.stage_slots,
                              bucket_bytes=cfg.bucket_bytes,
@@ -124,38 +109,29 @@ class SnapshotEngine:
                              trace=cfg.trace_protocol)
         self._own = self._own_plan()
         self._stripe = self._stripe_plan()
-        self._pipeline: Optional[SnapshotPipeline] = None
-        if cfg.pipeline:
-            self._pipeline = SnapshotPipeline(self.smp, self.spec, cfg,
-                                              self._own, self._stripe,
-                                              leaf_arrays(state_template))
-        self._max_flights = max(1, int(getattr(cfg, "max_flights", 1))) \
-            if cfg.pipeline else 1
-        # dirty-delta snapshotting: only meaningful on the pipelined path
-        # with a single flight in the air (a delta's base must be the
-        # SMP's latest clean step, which overlap would race)
+        self._pipeline = SnapshotPipeline(self.smp, self.spec, cfg,
+                                          self._own, self._stripe,
+                                          leaf_arrays(state_template))
+        # dirty-delta snapshotting: a delta's base is the SMP's latest
+        # clean step, which the one flight in the air publishes
         self._tracker: Optional[DeltaTracker] = None
         self._delta_log: Optional[DeltaLog] = None
         self._dirty_provider = None
-        if getattr(cfg, "delta", False) and cfg.pipeline \
-                and self._max_flights == 1:
+        if cfg.delta:
             self._tracker = DeltaTracker(
-                keyframe_every=max(1, int(getattr(cfg, "delta_keyframe",
-                                                  8))),
-                dirty_threshold=float(getattr(cfg, "delta_dirty_threshold",
-                                              0.6)),
-                digest=bool(getattr(cfg, "delta_digest", True)))
+                keyframe_every=max(1, int(cfg.delta_keyframe)),
+                dirty_threshold=float(cfg.delta_dirty_threshold),
+                digest=bool(cfg.delta_digest))
             self._delta_log = DeltaLog()
-        self._flight_bytes = sum(t.hi - t.lo
-                                 for t in self._pipeline.schedule) \
-            if self._pipeline is not None else self.spec.total_bytes
-        self._flights: List[PipelineFlight] = []
+        self._flight_bytes = sum(t.hi - t.lo for t in self._pipeline.schedule)
+        # the flight this engine owns: live, or finished and not yet
+        # collected; None once collected
+        self._flight: Optional[PipelineFlight] = None
         # results of collected flights, oldest first, for the facade to
         # emit (`snapshot-published`); a caller that never reads them
         # keeps the newest 64
         self.published: "collections.deque[PipelineResult]" = \
             collections.deque(maxlen=64)
-        self._thread: Optional[threading.Thread] = None    # serial mode
         self._err: Optional[BaseException] = None
         self.degraded = False      # SMP unreachable: snapshots paused, not fatal
         self.first_error: Optional[str] = None   # first failed flight, for
@@ -163,13 +139,12 @@ class SnapshotEngine:
         # mutable copy of cfg.persist_delay_s: ReftConfig is frozen, but
         # fault injection (slow-persist / slow-NFS scenarios) must be able
         # to raise durable-tier latency mid-run
-        self.persist_delay_s = float(getattr(cfg, "persist_delay_s", 0.0))
+        self.persist_delay_s = float(cfg.persist_delay_s)
         self.last_clean_step = -1
         self._persists: Dict[int, dict] = {}    # seq -> in-flight record
         self.stats = {"snapshots": 0, "bytes_sent": 0, "seconds": 0.0,
                       **{f"{k}_seconds": 0.0 for k in LEVELS},
                       "gather_bytes": 0, "gather_packed_bytes": 0,
-                      "overlapped_flights": 0,
                       "persists": 0, "persist_inflight": 0,
                       "persist_seconds": 0.0,
                       "persist_overlap_seconds": 0.0,
@@ -178,19 +153,11 @@ class SnapshotEngine:
                       "persist_upload_seconds": 0.0,
                       "persist_upload_bytes": 0,
                       "persist_upload_retries": 0,
-                      "device_encode": (self._pipeline.device_encode
-                                        if self._pipeline else False),
-                      "ranged_fetch": (self._pipeline.ranged_fetch
-                                       if self._pipeline else False),
+                      "device_encode": self._pipeline.device_encode,
+                      "ranged_fetch": self._pipeline.ranged_fetch,
                       "stager_affinity": None,
                       "skipped_buckets": 0, "delta_flights": 0,
                       "keyframe_flights": 0, "delta_base_misses": 0}
-
-    @property
-    def _flight(self) -> Optional[PipelineFlight]:
-        """Newest owned flight (back-compat accessor; multi-flight engines
-        own a queue)."""
-        return self._flights[-1] if self._flights else None
 
     # ------------------------------------------------------------- plan
     def _own_plan(self) -> List[Tuple[int, int, int]]:
@@ -213,57 +180,41 @@ class SnapshotEngine:
 
     # -------------------------------------------------------- snapshot
     def in_flight(self) -> bool:
-        if any(f.in_flight() for f in self._flights):
-            return True
-        return self._thread is not None and self._thread.is_alive()
+        return self._flight is not None and self._flight.in_flight()
 
     def snapshot_async(self, state: Any, step: int,
                        extra_meta: dict = None) -> bool:
-        """Fire-and-forget; returns False when no flight slot is free
-        (frequency self-limits to the achievable rate, Figure 4).  With
-        `max_flights > 1` a new flight may launch while its predecessor
-        is still draining L2/L3 (multi-flight overlap)."""
+        """Fire-and-forget; returns False while the previous flight is
+        still in the air (frequency self-limits to the achievable rate,
+        Figure 4)."""
         with span("repro.hasc.launch"):
             return self._launch(state, step, extra_meta)
 
     def ready(self) -> bool:
         """Whether a snapshot asked for now would launch; collects the
-        flights that have finished first."""
+        flight first if it has finished."""
         if self.degraded:
             return False
-        if self._thread is not None and self._thread.is_alive():
-            return False                       # serial mode: single flight
         self._collect_finished()
         self._raise_pending()
         if self.degraded:                  # the drain just found a dead SMP
             return False
-        return len(self._flights) < self._max_flights
+        return self._flight is None
 
     def _launch(self, state: Any, step: int, extra_meta: dict) -> bool:
         if not self.ready():
             return False
         leaves = leaf_arrays(state)                    # pin the references
-        if self._pipeline is not None:
-            overlapped = any(f.in_flight() for f in self._flights)
-            plan = None
-            if self._tracker is not None:
-                ranges = None
-                if self._dirty_provider is not None:
-                    ranges = self._dirty_provider()
-                plan = self._tracker.plan(self.last_clean_step,
-                                          self._pipeline.schedule, ranges,
-                                          self.spec.total_bytes)
-            self._flights.append(self._pipeline.start(leaves, int(step),
-                                                      extra_meta or {},
-                                                      delta=plan))
-            if overlapped:
-                self.stats["overlapped_flights"] += 1
-            return True
-        self._thread = threading.Thread(
-            target=self._run_serial, args=(leaves, int(step),
-                                           extra_meta or {}),
-            daemon=True, name=f"snap-n{self.node}")
-        self._thread.start()
+        plan = None
+        if self._tracker is not None:
+            ranges = None
+            if self._dirty_provider is not None:
+                ranges = self._dirty_provider()
+            plan = self._tracker.plan(self.last_clean_step,
+                                      self._pipeline.schedule, ranges,
+                                      self.spec.total_bytes)
+        self._flight = self._pipeline.start(leaves, int(step),
+                                            extra_meta or {}, delta=plan)
         return True
 
     def set_dirty_provider(self, fn) -> None:
@@ -282,38 +233,28 @@ class SnapshotEngine:
         return self.wait()
 
     def wait(self, timeout: float = 300.0) -> int:
-        """Drain every in-flight snapshot (oldest first).  On timeout the
-        live flight handles are KEPT (a snapshot can never be dropped
-        while live) and a `TimeoutError` is raised instead."""
-        deadline = time.monotonic() + timeout
-        while self._flights:
-            left = max(0.0, deadline - time.monotonic())
-            self._collect_flight(left)         # raises TimeoutError if live
-        if self._thread is not None:
-            self._thread.join(timeout)
-            if self._thread.is_alive():
-                raise TimeoutError(
-                    f"serial snapshot thread still running after "
-                    f"{timeout:.1f}s; still in flight")
-            self._thread = None
+        """Drain the in-flight snapshot.  On timeout the live flight
+        handle is KEPT (a snapshot can never be dropped while live) and a
+        `TimeoutError` is raised instead."""
+        self._collect_flight(timeout)          # raises TimeoutError if live
         self._raise_pending()
         return self.last_clean_step
 
     def _collect_finished(self):
-        """Fold every already-finished flight (oldest first) into stats
-        without blocking on the live ones."""
-        while self._flights and self._flights[0].done.is_set():
+        """Fold the flight into stats if it has finished, without
+        blocking on a live one."""
+        if self._flight is not None and self._flight.done.is_set():
             self._collect_flight(0.0)
 
     def _collect_flight(self, timeout: float):
-        """Fold the OLDEST flight into stats.  A TimeoutError from a flight
+        """Fold the owned flight into stats.  A TimeoutError from a flight
         that is genuinely still LIVE propagates (the flight stays owned);
         a flight that FAILED with an internal TimeoutError (e.g. the SMP
         ack timed out) is a dead flight and is routed through _err so the
-        engine degrades exactly like the serial path."""
-        if not self._flights:
+        engine degrades."""
+        flight = self._flight
+        if flight is None:
             return
-        flight = self._flights[0]
         try:
             res = flight.wait(timeout)
         except TimeoutError:
@@ -322,14 +263,14 @@ class SnapshotEngine:
             try:                               # finished during the wait:
                 res = flight.wait(0.0)         # collect its real outcome
             except BaseException as e:
-                self._flights.pop(0)
+                self._flight = None
                 self._flight_failed(e)
                 return                         # surfaced by _raise_pending
         except BaseException as e:
-            self._flights.pop(0)
+            self._flight = None
             self._flight_failed(e)
             return                             # surfaced by _raise_pending
-        self._flights.pop(0)
+        self._flight = None
         self.last_clean_step = res.clean_step
         st = self.stats
         st["snapshots"] += 1
@@ -340,8 +281,7 @@ class SnapshotEngine:
         st["gather_bytes"] += res.gather_bytes
         st["gather_packed_bytes"] += res.gather_packed_bytes
         self.published.append(res)
-        if self._pipeline is not None:
-            st["stager_affinity"] = self._pipeline.applied_affinity
+        st["stager_affinity"] = self._pipeline.applied_affinity
         if self._tracker is not None:
             was_delta = res.delta_base is not None
             frac = (res.bytes_sent / self._flight_bytes
@@ -385,67 +325,6 @@ class SnapshotEngine:
                 return
             raise err
 
-    # ------------------------------------------------- serial baseline
-    def _run_serial(self, leaves, step, extra_meta):
-        """Pre-refactor monolithic path (read -> CRC -> blocking ring send
-        per bucket), kept as the interference baseline the HASC pipeline
-        is measured against (`ReftConfig(pipeline=False)`)."""
-        try:
-            import zlib
-            t0 = time.time()
-            for leaf in leaves:
-                try:
-                    leaf.copy_to_host_async()
-                except AttributeError:
-                    pass
-            budget = leaf_budget(
-                self.spec, [(lo, hi) for _, lo, hi in self._own]
-                + list(self._stripe))
-            reader = LeafReader(self.spec, leaves, budget)
-            bb = self.cfg.bucket_bytes
-            scratch = np.empty(bb, np.uint8)
-            sent = 0
-            crc = 0
-            l1 = l2 = l3 = 0.0
-            t = time.perf_counter()
-            self.smp.begin(step)
-            l3 += time.perf_counter() - t
-            for dst0, lo, hi in self._own:
-                for a in range(lo, hi, bb):
-                    b = min(a + bb, hi)
-                    t = time.perf_counter()
-                    reader.read(a, b, scratch[:b - a])
-                    crc = zlib.crc32(scratch[:b - a], crc)
-                    l1 += time.perf_counter() - t
-                    t = time.perf_counter()
-                    self.smp.send_bucket(0, dst0 + (a - lo), scratch[:b - a])
-                    l2 += time.perf_counter() - t
-                    sent += b - a
-            for lo, hi in self._stripe:
-                for a in range(lo, hi, bb):
-                    b = min(a + bb, hi)
-                    t = time.perf_counter()
-                    reader.read(a, b, scratch[:b - a])
-                    l1 += time.perf_counter() - t
-                    t = time.perf_counter()
-                    self.smp.send_bucket(1, a - lo, scratch[:b - a])
-                    l2 += time.perf_counter() - t
-                    sent += b - a
-            meta = {"spec": self.spec.to_json(), "step": step,
-                    "extra": extra_meta, "crc_own": crc}
-            t = time.perf_counter()
-            self.smp.end(step, pickle.dumps(meta))
-            self.last_clean_step = self.smp.wait_clean()
-            l3 += time.perf_counter() - t
-            self.stats["snapshots"] += 1
-            self.stats["bytes_sent"] += sent
-            self.stats["seconds"] += time.time() - t0
-            self.stats["l1_seconds"] += l1
-            self.stats["l2_seconds"] += l2
-            self.stats["l3_seconds"] += l3
-        except BaseException as e:                      # surfaced on wait()
-            self._flight_failed(e)
-
     # ------------------------------------------------------------ ckpt
     def delta_extents_since(self, base: Optional[int],
                             step: int) -> Optional[List[Tuple[int, int]]]:
@@ -470,7 +349,7 @@ class SnapshotEngine:
         shard carrying only the extents rewritten since that base — the
         caller must have verified the chain via `delta_extents_since`."""
         opts = {}
-        bw = float(getattr(self.cfg, "persist_bw_limit", 0.0) or 0.0)
+        bw = float(self.cfg.persist_bw_limit or 0.0)
         if bw > 0:
             opts["bw_limit"] = bw
         if remote:

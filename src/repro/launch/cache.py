@@ -1,9 +1,9 @@
 """Where compiled programs are cached between runs.
 
-Every entry point (`repro.launch.train`, `repro.supervise.run`, the
-benchmarks, `chip_smoke.py`) calls `use_compile_cache()` before its first
-compile.  If `JAX_COMPILATION_CACHE_DIR` is set, JAX already reads it and
-nothing else is set.  Otherwise the cache lives at one fixed path inside
+Every entry point (`repro.launch.train`, `repro.supervise.run`, `bench/`,
+`benchmarks/`, `chip_smoke.py`) calls `use_compile_cache()` before its
+first compile.  If `JAX_COMPILATION_CACHE_DIR` is set, JAX already reads
+it and nothing else is set.  Otherwise the cache lives at one fixed path inside
 the checkout (`<repo>/.jax_cache`, git-ignored): the path is part of a
 cache entry's key, so it must not be built from a temporary name, a
 process id or the time.

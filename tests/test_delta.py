@@ -344,6 +344,49 @@ def test_keyframe_forced_at_dirty_threshold_and_shm_identity():
         eng.close()
 
 
+@pytest.mark.parametrize("device_encode", ["off", "on"])
+def test_two_of_eight_dirty_experts_send_at_most_half_the_bytes(
+        device_encode, tmp_path):
+    """An 8-expert MoE state whose router reports 2 experts touched: a
+    delta flight sends at most half the bytes of a full flight of the same
+    state and bucket geometry (bytes only: they are deterministic)."""
+    from repro.api import CheckpointSpec
+    E, dirty_experts, d = 8, 2, 64
+    rng = np.random.RandomState(0)
+
+    def mk(*sh):
+        return jnp.asarray(rng.rand(*sh), jnp.float32)
+
+    state = {"router": mk(256, E), "wi_gate": mk(E, d, d), "wo": mk(E, d, d)}
+    touched = [i < dirty_experts for i in range(E)]
+    mutated = dict(state)
+    for k in ("wi_gate", "wo"):
+        mutated[k] = state[k].at[:dirty_experts].add(1.0)
+    sent = {}
+    for mode, opts in (("full", {}),
+                       ("delta", {"delta": True, "delta_keyframe": 10 ** 6,
+                                  "delta_dirty_threshold": 0.9})):
+        spec = CheckpointSpec(backend="reft", ckpt_dir=str(tmp_path / mode),
+                              sg_size=2, bucket_bytes=4096, resume=False,
+                              options={**opts,
+                                       "device_encode": device_encode})
+        with spec.build(state) as ck:
+            if mode == "delta":
+                fspec = ck.group.engines[0].spec
+                ck.set_dirty_provider(
+                    lambda: expert_dirty_ranges(fspec, touched))
+            assert ck.snapshot(state, 1, wait=True)   # delta: the keyframe
+            b0 = ck.stats()["engine_bytes_sent"]
+            assert ck.snapshot(mutated, 2, wait=True)
+            st = ck.stats()
+            sent[mode] = st["engine_bytes_sent"] - b0
+            if mode == "delta":
+                assert st["delta_flights"] == 2       # one per member
+            res = ck.restore()
+            assert res.step == 2 and trees_equal(res.state, mutated)
+    assert 0 < sent["delta"] <= 0.5 * sent["full"], sent
+
+
 def test_delta_family_elastic_resume_and_local_scrub(tmp_path):
     """n=3 delta family: elastic resume into a 5-member SG from a delta
     step, then the scrubber detects + repairs a corrupted `.reftd`."""

@@ -1,6 +1,6 @@
 """HASC saving pipeline: schedule ordering, interference, backpressure,
 wait-timeout semantics, leaf-cache eviction, per-level accounting,
-device-side encode equivalence, multi-flight overlap, saving-path
+device-side encode equivalence, back-to-back flights, saving-path
 affinity."""
 import os
 import tempfile
@@ -101,13 +101,13 @@ def test_leaf_reader_unbudgeted_keeps_cache():
 
 
 # ------------------------------------------------------------ interference
-@pytest.mark.parametrize("pipelined", [True, False])
-def test_training_steps_proceed_while_snapshot_in_flight(pipelined):
+@pytest.mark.parametrize("device_encode", ["off", "on"])
+def test_training_steps_proceed_while_snapshot_in_flight(device_encode):
     state = {"opt_mu": jnp.zeros((1 << 18,), jnp.float32),
              "w": jnp.ones((1 << 18,), jnp.float32)}
     eng = SnapshotEngine(0, 1, state,
-                         ReftConfig(pipeline=pipelined, bucket_bytes=1 << 12,
-                                    stage_slots=4))
+                         ReftConfig(bucket_bytes=1 << 12, stage_slots=4,
+                                    device_encode=device_encode))
     try:
         assert eng.snapshot_async(state, 1)
         steps_during_flight = 0
@@ -198,14 +198,14 @@ def test_finished_flight_and_closed_group_pin_no_state():
 
 
 # ------------------------------------------------------- wait() semantics
-@pytest.mark.parametrize("pipelined", [True, False])
-def test_wait_timeout_keeps_flight_live(pipelined):
+@pytest.mark.parametrize("device_encode", ["off", "on"])
+def test_wait_timeout_keeps_flight_live(device_encode):
     """Satellite fix: a timed-out wait() must NOT drop the handle — a
     second snapshot can never overlap a live one."""
     state = {"opt_mu": jnp.zeros((1 << 19,), jnp.float32)}
     eng = SnapshotEngine(0, 1, state,
-                         ReftConfig(pipeline=pipelined, bucket_bytes=1 << 11,
-                                    stage_slots=2))
+                         ReftConfig(bucket_bytes=1 << 11, stage_slots=2,
+                                    device_encode=device_encode))
     try:
         assert eng.snapshot_async(state, 1)
         with pytest.raises(TimeoutError):
@@ -307,8 +307,8 @@ def test_smp_death_mid_flight_degrades_not_wedges():
 
 def test_flight_internal_timeout_degrades_not_wedges():
     """A flight that FAILS with an internal TimeoutError (SMP ack timeout)
-    is a dead flight: the engine must degrade — like the serial path —
-    not keep the corpse as 'still live' and wedge every later call."""
+    is a dead flight: the engine must degrade, not keep the corpse as
+    'still live' and wedge every later call."""
     state = opt_state(1 << 10)
     eng = SnapshotEngine(0, 1, state, ReftConfig(bucket_bytes=1 << 12))
     try:
@@ -483,41 +483,42 @@ def test_sg4_device_encode_raim5_roundtrip():
         g.close()
 
 
-# --------------------------------------------------------- multi-flight
+# ---------------------------------------------------- back-to-back flights
 @pytest.mark.parametrize("device_encode", ["off", "on"])
-def test_multi_flight_overlap_no_data_loss_bounded_scratch(device_encode):
-    """max_flights=2: snapshot N+1 launches while N is still draining; both
-    land bit-identically in the SMP triple buffer (no loss, no clobber)
-    and the SHARED scratch pool never exceeds `scratch_buffers` credits."""
+def test_back_to_back_flights_land_and_scratch_is_bounded(device_encode):
+    """Two flights, one after the other: each lands bit-identically in the
+    SMP triple buffer, the restore returns the newer one, and the
+    pipeline's scratch pool holds `scratch_buffers` credits afterwards."""
+    from repro.core.recovery import restore_state
+    from repro.core.smp import ReadOnlyNode
+    from repro.core.treebytes import tree_to_buffer
     state = {"opt_mu": jnp.zeros((1 << 15,), jnp.float32),
              "w": jnp.ones((1 << 15,), jnp.float32)}
     state2 = jax.tree.map(lambda x: x + 1, state)
     eng = SnapshotEngine(0, 1, state,
                          ReftConfig(bucket_bytes=1 << 12, stage_slots=4,
-                                    max_flights=2, scratch_buffers=2,
+                                    scratch_buffers=2,
                                     device_encode=device_encode))
     try:
         assert eng.snapshot_async(state, 1)
-        assert eng.snapshot_async(state2, 2)          # overlapped launch
-        assert not eng.snapshot_async(state2, 3)      # over the credit
+        assert eng.wait() == 1
+        assert eng.snapshot_async(state2, 2)
+        assert not eng.snapshot_async(state2, 3)      # one flight at a time
         assert eng.wait() == 2
-        assert eng.stats["snapshots"] == 2
-        assert eng.stats["overlapped_flights"] >= 1
+        assert eng.stats["snapshots"] == 2 and eng._flight is None
         pool = eng._pipeline
-        assert pool._free.qsize() == pool.scratch_buffers   # fixed scratch
-        from repro.core.recovery import restore_state
-        from repro.core.smp import ReadOnlyNode
-        from repro.core.treebytes import tree_to_buffer
+        assert pool._free.qsize() == pool.scratch_buffers
         rec, step, _ = restore_state(eng.run, 1, eng.spec.total_bytes,
                                      state, [0])
         assert step == 2 and trees_equal(rec, state2)
         view = ReadOnlyNode(eng.run, 0, 1, eng.spec.total_bytes)
         try:
             assert {1, 2} <= set(view.clean_steps())
-            flat1 = np.empty(eng.spec.total_bytes, np.uint8)
-            tree_to_buffer(state, eng.spec, flat1)
-            assert np.array_equal(
-                view.read_own(1)[:eng.spec.total_bytes], flat1)
+            for st, want in ((1, state), (2, state2)):
+                flat = np.empty(eng.spec.total_bytes, np.uint8)
+                tree_to_buffer(want, eng.spec, flat)
+                assert np.array_equal(
+                    view.read_own(st)[:eng.spec.total_bytes], flat)
         finally:
             view.close()
     finally:
@@ -627,16 +628,16 @@ def test_reft_backend_reports_gather_counters():
                 st["engine_gather_bytes"]
 
 
-def test_serial_fallback_via_options():
+@pytest.mark.parametrize("key,value", [("pipeline", False),
+                                       ("max_flights", 2)])
+def test_reft_options_reject_removed_modes(key, value):
+    """The serial thread and overlapped flights are gone: asking for
+    either is refused by name, never run as a different mode."""
     from repro.api import CheckpointSpec
-    import tempfile
-    state = opt_state(1 << 12)
+    state = opt_state(1 << 10)
     with tempfile.TemporaryDirectory() as d:
         spec = CheckpointSpec(backend="reft", ckpt_dir=d, sg_size=2,
                               resume=False, bucket_bytes=1 << 12,
-                              options={"pipeline": False})
-        with spec.build(state) as ck:
-            assert ck.group.engines[0]._pipeline is None
-            assert ck.snapshot(state, 1, wait=True)
-            res = ck.restore()
-            assert res.step == 1 and trees_equal(res.state, state)
+                              options={key: value})
+        with pytest.raises(ValueError, match=key):
+            spec.build(state)
